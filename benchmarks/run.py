@@ -209,17 +209,22 @@ def main() -> int:
                      f"disabled_pct={doc['disabled_overhead_pct']};"
                      f"disabled_ok={doc['disabled_overhead_ok']}"))
 
+    # Host-only lanes first, the lanes that execute on the JAX device
+    # (table7_8, frontend_cosim, fuzz_throughput) last: dse, arch_dse and
+    # serving fork worker fleets, and a child forked after this process has
+    # opened the accelerator runtime inherits a chip that belongs to one
+    # process at a time.
     lane("fig7_table4", lane_fig7)
-    lane("table7_8", lane_table7_8)
     lane("solver_opts", lane_solver_opts)
     lane("incremental_solver", lane_incremental)
     lane("portfolio", lane_portfolio)
     lane("dse", lane_dse)
     lane("arch_dse", lane_arch_dse)
     lane("serving", lane_serving)
+    lane("obs_overhead", lane_obs)
+    lane("table7_8", lane_table7_8)
     lane("frontend_cosim", lane_frontend)
     lane("fuzz_throughput", lane_fuzz)
-    lane("obs_overhead", lane_obs)
 
     with open("results/bench_lanes.json", "w") as fh:
         json.dump({"lanes": lane_walls,

@@ -92,8 +92,7 @@ def preset_state(asm: AssembledCIL, num_pes: int, mem: np.ndarray,
 
 
 def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
-                batch: int = 1, backend: str = "ref",
-                interpret: bool = True):
+                batch: int = 1, backend: str = "ref"):
     """Run an already-assembled CIL over ``batch`` memories in one
     dispatch.  Returns ``(final_state, outs (T, B, P), out0 (B, P))`` —
     the shared execution seam under :func:`simulate` and the batched
@@ -104,17 +103,15 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
     state = preset_state(asm, grid.num_pes, mem, batch)
     out0 = np.array(state.out)
     nbrs = neighbor_table(grid)
-    final, outs = run_program(fields, state, nbrs, backend=backend,
-                              interpret=interpret)
+    final, outs = run_program(fields, state, nbrs, backend=backend)
     return final, np.asarray(outs), out0
 
 
 def simulate(program: LoopBuilder, mapping: Mapping, mem: np.ndarray,
-             batch: int = 1, backend: str = "ref",
-             interpret: bool = True) -> SimResult:
+             batch: int = 1, backend: str = "ref") -> SimResult:
     asm = assemble(program, mapping)
     final, outs, _ = execute_asm(asm, mapping.grid, mem, batch=batch,
-                                 backend=backend, interpret=interpret)
+                                 backend=backend)
     node_values: Dict[int, np.ndarray] = {}
     last_iter = program.trip - 1
     for (t, pe), (n, j) in asm.node_of_cell.items():

@@ -467,7 +467,6 @@ def run_stacked(
     grid,
     mems: np.ndarray,
     backend: str = "ref",
-    interpret: bool = True,
 ):
     """Execute K same-grid bitstreams over (K, B, M) memories in one
     ``vmap``-ed dispatch.  Returns (final PEState with a leading K axis,
@@ -502,8 +501,7 @@ def run_stacked(
     nbrs = neighbor_table(grid)
 
     def run_one(f, s):
-        return run_program(f, s, nbrs, backend=backend,
-                           interpret=interpret)
+        return run_program(f, s, nbrs, backend=backend)
 
     final, outs = jax.vmap(run_one)(stacked_fields, stacked_state)
     return final, np.asarray(outs)

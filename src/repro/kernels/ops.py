@@ -2,7 +2,7 @@
 
 ``run_program`` scans the decoded (T, P) instruction grid over the cycle
 step — the ref (pure jnp) or the Pallas kernel — carrying the PE-array
-state; batch rides along vectorized.
+state; batch rides along vectorized.  Any batch size works on both.
 """
 from __future__ import annotations
 
@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..cgra.isa import decode_program
-from .pe_array import cycle_step_pallas
+from .pe_array import cycle_step_pallas, padded_batch
 from .ref import InstrRow, PEState, cycle_step_ref
 
 
@@ -54,19 +54,31 @@ def init_state(batch: int, num_pes: int, mem: np.ndarray) -> PEState:
         mem=jnp.asarray(mem))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("neighbors", "backend", "interpret",
-                                    "trace"))
+@functools.partial(jax.jit, static_argnames=("neighbors", "backend", "trace"))
 def run_program(fields: InstrRow, state: PEState, neighbors,
-                backend: str = "ref", interpret: bool = True,
-                trace: bool = True):
-    """Scan all instruction rows. Returns (final state, out trace (T, B, P))."""
-    step = (cycle_step_ref if backend == "ref"
-            else functools.partial(cycle_step_pallas, interpret=interpret))
+                backend: str = "ref", trace: bool = True):
+    """Scan all instruction rows. Returns (final state, out trace (T, B, P)).
+
+    The Pallas step takes the batch in whole tiles: any other batch is
+    padded with zero rows, which are independent memories and so inert,
+    and the results are sliced back to the caller's batch."""
+    batch = state.out.shape[0]
+    if backend == "ref":
+        step, padded = cycle_step_ref, batch
+    else:
+        step = cycle_step_pallas
+        padded = padded_batch(batch, state.out.shape[1], state.mem.shape[1])
+    if padded != batch:
+        state = jax.tree_util.tree_map(
+            lambda x: jnp.pad(x, [(0, padded - batch)] + [(0, 0)] * (x.ndim - 1)),
+            state)
 
     def body(st, row):
         new = step(st, row, neighbors)
         return new, (new.out if trace else None)
 
     final, outs = jax.lax.scan(body, state, fields)
+    if padded != batch:
+        final = jax.tree_util.tree_map(lambda x: x[:batch], final)
+        outs = outs[:, :batch] if trace else outs
     return final, outs

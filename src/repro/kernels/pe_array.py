@@ -1,20 +1,25 @@
 """Pallas TPU kernel for the CGRA PE-array cycle step.
 
-TPU-native adaptation of the mapped-CIL executor (DESIGN.md §3): the batch
-dimension (independent input sets of the same CIL) rides the 128-lane axis,
-PEs ride sublanes — a (B_TILE, P) tile of the array state lives in VMEM and
-one kernel invocation advances it a full CGRA-cycle.
+One ``pallas_call`` advances the PE-array state of a batch of independent
+memories (the same CIL over different inputs) by one CGRA-cycle, with the
+semantics that ``kernels/ref.py`` defines.  The grid walks the batch in
+tiles of ``bt`` rows (:func:`batch_tile`).  Each tile holds ``(bt, P)``
+blocks of OUT and the flags (batch rows on sublanes, the P PEs on lanes), a
+``(bt, P, 4)`` register block and a ``(bt, M)`` data-memory block (memory
+words on lanes).
 
 Two deliberate deviations from a literal port:
 * neighbor OUT reads use *static* slicing (the torus is compile-time
   constant), so no dynamic gather is emitted;
-* data-memory load/store uses one-hot masking against the (B_TILE, M) memory
-  tile instead of scattered addressing — MXU/VPU-friendly and exactly
-  equivalent for in-range addresses (benchmark memories are 128-256 words).
+* data-memory load/store uses one-hot masking against the (bt, M) memory
+  tile instead of scattered addressing — exactly equivalent for in-range
+  addresses (benchmark memories are 128-256 words).
 
-Validated in interpret mode against kernels/ref.py across batch/P/M sweeps
-(tests/test_kernels.py); FXPMUL uses int32 here vs int64 in the oracle, so
-tests restrict FXPMUL operands to the non-overflowing range.
+The kernel runs compiled on an accelerator and interpreted on the CPU
+backend (:func:`interpret_mode`).  tests/test_kernels.py checks it against
+kernels/ref.py in interpret mode; tests/test_tpu_compile.py compiles it for
+a TPU v5e.  FXPMUL uses int32 here vs int64 in the oracle, so tests restrict
+FXPMUL operands to the non-overflowing range.
 """
 from __future__ import annotations
 
@@ -29,11 +34,40 @@ from jax.experimental import pallas as pl
 from ..cgra.isa import FXP_FRAC_BITS, OPCODE
 from .ref import InstrRow, PEState
 
-B_TILE = 128  # lane-axis tile
+B_TILE = 128  # most batch rows per grid step
+# Largest (rows, P, M) one-hot mask a grid step may hold, in int32 words,
+# with P padded to whole sublane tiles and M to at least one lane tile:
+# 64 rows of a 6x6 array over 128 words.  128 rows of 6x6 need ~21 MiB of
+# scoped VMEM against the 16 MiB limit of a TPU v5e.
+_TILE_WORDS = 64 * 40 * 128
+
+
+def batch_tile(num_pes: int, mem_words: int) -> int:
+    """Batch rows per grid step: ``B_TILE``, halved until the step's
+    one-hot memory mask, and with it the (rows, P, 4) register block whose
+    4 pads to a full lane tile, fits ``_TILE_WORDS``."""
+    per_row = -(-num_pes // 8) * 8 * max(mem_words, 128)
+    rows = B_TILE
+    while rows > 8 and rows * per_row > _TILE_WORDS:
+        rows //= 2
+    return rows
+
+
+def interpret_mode() -> bool:
+    """Pallas has no compiled lowering for the CPU backend: interpret
+    there, compile everywhere else."""
+    return jax.default_backend() == "cpu"
+
+
+def padded_batch(batch: int, num_pes: int, mem_words: int) -> int:
+    """Smallest batch >= ``batch`` the tiling accepts: up to one tile is a
+    single block, beyond that a whole number of tiles."""
+    bt = batch_tile(num_pes, mem_words)
+    return batch if batch <= bt else -(-batch // bt) * bt
 
 
 def _alu_block(op, a, b, sf, zf):
-    """Vectorized all-op ALU on a (B_TILE, P) block (int32)."""
+    """Vectorized all-op ALU on a (bt, P) block (int32)."""
     shift = b & 31
     prod = a * b
 
@@ -116,7 +150,7 @@ def _cycle_kernel(neighbors: Tuple[Tuple[int, int, int, int], ...],
     loaded = (onehot * mem[:, None, :]).sum(axis=2)
     res = jnp.where(is_load[None, :], loaded, res)
     # one-hot store
-    s_mask = onehot * is_store[None, :, None].astype(jnp.int32)
+    s_mask = onehot * is_store.astype(jnp.int32)[None, :, None]
     any_store = s_mask.sum(axis=1)                         # (B, M)
     store_val = (s_mask * b[:, :, None]).sum(axis=1)       # (B, M)
     mem = jnp.where(any_store > 0, store_val, mem)
@@ -125,13 +159,9 @@ def _cycle_kernel(neighbors: Tuple[Tuple[int, int, int, int], ...],
     out = jnp.where(executed, res, out)
     sf = jnp.where(executed, (res < 0).astype(jnp.int32), sf)
     zf = jnp.where(executed, (res == 0).astype(jnp.int32), zf)
-    new_regs = regs
-    for k in range(4):
-        hit = executed & (dst == k)[None, :]
-        new_regs = new_regs.at[:, :, k].set(
-            jnp.where(hit, res, new_regs[:, :, k]))
-
-    regs_o[...] = new_regs
+    regs_o[...] = jnp.stack(
+        [jnp.where(executed & (dst == k)[None, :], res, regs[:, :, k])
+         for k in range(4)], axis=2)
     out_o[...] = out
     sf_o[...] = sf
     zf_o[...] = zf
@@ -139,12 +169,13 @@ def _cycle_kernel(neighbors: Tuple[Tuple[int, int, int, int], ...],
 
 
 def cycle_step_pallas(state: PEState, instr: InstrRow,
-                      neighbors, *, interpret: bool = True) -> PEState:
-    """One CGRA-cycle via pl.pallas_call, tiled over the batch axis."""
+                      neighbors) -> PEState:
+    """One CGRA-cycle via pl.pallas_call, tiled over the batch axis (the
+    batch must be a :func:`padded_batch`)."""
     regs, out, sf, zf, mem = state
     B, P = out.shape
     M = mem.shape[1]
-    bt = min(B_TILE, B)
+    bt = min(batch_tile(P, M), B)
     if B % bt:
         raise ValueError(f"batch {B} not divisible by tile {bt}")
     grid = (B // bt,)
@@ -179,7 +210,7 @@ def cycle_step_pallas(state: PEState, instr: InstrRow,
             bspec((bt, M), lambda i: (i, 0)),
         ],
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(instr.op, instr.dst, instr.sa, instr.sb, instr.imm,
       regs, out, sf, zf, mem)
     return PEState(regs=regs_n, out=out_n, sf=sf_n, zf=zf_n, mem=mem_n)

@@ -36,6 +36,7 @@ import time
 from typing import List, Optional
 
 from ..core.mapper import MapperConfig
+from ..kernels import enable_compile_cache
 from .session import Toolchain
 
 
@@ -245,6 +246,7 @@ def _cmd_list(args) -> int:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     # cosim/sweep forward verbatim to the existing sub-CLIs; dispatch
     # before argparse so their own flags (argparse's REMAINDER chokes on

@@ -1,6 +1,8 @@
 """Pallas PE-array kernel vs pure-jnp oracle: shape/value sweeps.
 
-The Pallas kernel runs in interpret mode (CPU container; TPU is the target).
+Under ``JAX_PLATFORMS=cpu`` the Pallas kernel runs in interpret mode
+(``pe_array.interpret_mode``); tests/test_tpu_compile.py compiles it for a
+TPU v5e.
 """
 import numpy as np
 import pytest
@@ -15,7 +17,8 @@ from repro.cgra import make_grid
 from repro.cgra.isa import DST_NONE, Instr, OPCODE, OPS, encode_program
 from repro.cgra.simulator import neighbor_table
 from repro.kernels.ops import decode_fields, init_state, run_program
-from repro.kernels.pe_array import cycle_step_pallas
+from repro.kernels.pe_array import (B_TILE, batch_tile, cycle_step_pallas,
+                                    padded_batch)
 from repro.kernels.ref import InstrRow, PEState, cycle_step_ref
 
 ALU_OPS = ["SADD", "SSUB", "SMUL", "SLT", "SRT", "SRA", "LAND", "LOR",
@@ -68,8 +71,7 @@ def test_pallas_matches_ref_random_programs(rows_cols, batch, M):
                         jnp.int32))
     nbrs = neighbor_table(grid)
     f_ref, o_ref = run_program(fields, state, nbrs, backend="ref")
-    f_pal, o_pal = run_program(fields, state, nbrs, backend="pallas",
-                               interpret=True)
+    f_pal, o_pal = run_program(fields, state, nbrs, backend="pallas")
     np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_pal))
     for a, b in zip(f_ref, f_pal):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -89,6 +91,87 @@ def test_pallas_matches_ref_property(seed):
     f_pal, o_pal = run_program(fields, state, nbrs, backend="pallas")
     np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_pal))
     np.testing.assert_array_equal(np.asarray(f_ref.mem), np.asarray(f_pal.mem))
+
+
+@pytest.mark.parametrize("rows_cols,mem_words,tile", [
+    ((2, 2), 128, B_TILE), ((4, 4), 128, B_TILE), ((4, 4), 256, 64),
+    ((5, 5), 128, 64), ((6, 6), 128, 64), ((8, 8), 128, 32),
+])
+def test_batch_tile_shrinks_with_pes_and_memory(rows_cols, mem_words, tile):
+    P = rows_cols[0] * rows_cols[1]
+    assert batch_tile(P, mem_words) == tile
+    assert padded_batch(tile - 1, P, mem_words) == tile - 1   # one block
+    assert padded_batch(tile, P, mem_words) == tile
+    assert padded_batch(tile + 1, P, mem_words) == 2 * tile
+    assert padded_batch(8192, P, mem_words) == 8192
+
+
+def test_ragged_batch_pallas_matches_ref_through_execute_asm():
+    """200 memories are one whole 128-row tile plus 72 rows: the Pallas
+    path pads the batch with inert rows and slices it back, so it returns
+    exactly the ref path's state and trace for the caller's 200 rows."""
+    from repro.cgra.registry import ensure_registered
+    from repro.cgra.simulator import execute_asm
+    from repro.core.mapper import MapperConfig
+    from repro.fuzz.corpus import make_corpus
+    from repro.fuzz.engine import batched_oracle, node_values_from_outs
+    from repro.toolchain.session import Toolchain
+
+    ensure_registered()
+    tc = Toolchain("2x2", MapperConfig(per_ii_timeout_s=60.0,
+                                       total_timeout_s=120.0))
+    prog = tc.program("bitcount")
+    mapping = tc.map(prog).mapping
+    asm = tc.assemble(prog, mapping)
+    mems = make_corpus("bitcount", 200, seed=5)
+    assert padded_batch(200, mapping.grid.num_pes, mems.shape[1]) == 256
+    f_ref, o_ref, _ = execute_asm(asm, mapping.grid, mems, batch=200,
+                                  backend="ref")
+    f_pal, o_pal, _ = execute_asm(asm, mapping.grid, mems, batch=200,
+                                  backend="pallas")
+    assert o_pal.shape == o_ref.shape == (len(asm.rows), 200, 4)
+    np.testing.assert_array_equal(o_ref, o_pal)
+    for a, b in zip(f_ref, f_pal):
+        assert a.shape[0] == 200
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and both are the oracle's answer, row for row
+    oracle_vals, oracle_mem = batched_oracle(prog.builder, mems)
+    np.testing.assert_array_equal(np.asarray(f_pal.mem), oracle_mem)
+    got = node_values_from_outs(asm, o_pal, prog.builder.trip)
+    for n, vals in got.items():
+        if n in oracle_vals:
+            np.testing.assert_array_equal(vals, oracle_vals[n])
+
+
+def test_pallas_interprets_only_on_the_cpu_backend():
+    import jax
+
+    from repro.kernels.pe_array import interpret_mode
+    assert interpret_mode() == (jax.default_backend() == "cpu")
+
+
+def test_compile_cache_sits_at_a_fixed_ignored_path(monkeypatch):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache goes to .jax_cache in the
+    checkout, which git ignores.  Set: the code names no directory."""
+    import pathlib
+
+    import jax
+
+    from repro import kernels
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert kernels.CACHE_DIR == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split("\n")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        kernels.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(kernels.CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", before)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(repo / "elsewhere"))
+        kernels.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_isa_encode_decode_roundtrip():
