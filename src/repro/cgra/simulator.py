@@ -97,14 +97,33 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
     dispatch.  Returns ``(final_state, outs (T, B, P), out0 (B, P))`` —
     the shared execution seam under :func:`simulate` and the batched
     fuzzing engine (``repro.fuzz.engine``), which also needs the preset
-    initial OUT values for switching-activity harvesting."""
+    initial OUT values for switching-activity harvesting.
+
+    Spans (``repro.obs``): ``verify.seam`` (timed) holds ``verify.decode``,
+    ``verify.preset``, ``verify.dispatch`` (trace, compile on a miss,
+    enqueue), ``verify.wait`` (the device run) and ``verify.transfer``
+    (the OUT trace to the host); ``d2h_bytes`` counts what comes back."""
+    import jax
+
     from ..kernels.ops import decode_fields, run_program
-    fields = decode_fields(asm.words())
-    state = preset_state(asm, grid.num_pes, mem, batch)
-    out0 = np.array(state.out)
-    nbrs = neighbor_table(grid)
-    final, outs = run_program(fields, state, nbrs, backend=backend)
-    return final, np.asarray(outs), out0
+    from ..obs import trace as obs_trace
+
+    with obs_trace.timed_span("verify.seam"):
+        with obs_trace.span("verify.decode"):
+            fields = decode_fields(asm.words())
+        with obs_trace.span("verify.preset") as sp:
+            state = preset_state(asm, grid.num_pes, mem, batch)
+            sp.set(d2h_bytes=state.out.nbytes + state.regs.nbytes)
+        out0 = np.array(state.out)
+        nbrs = neighbor_table(grid)
+        with obs_trace.span("verify.dispatch"):
+            final, outs = run_program(fields, state, nbrs, backend=backend)
+        with obs_trace.span("verify.wait"):
+            jax.block_until_ready((final, outs))
+        with obs_trace.span("verify.transfer") as sp:
+            outs = np.asarray(outs)
+            sp.set(d2h_bytes=outs.nbytes)
+    return final, outs, out0
 
 
 def simulate(program: LoopBuilder, mapping: Mapping, mem: np.ndarray,

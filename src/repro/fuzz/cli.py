@@ -5,6 +5,8 @@ Examples::
     repro fuzz --kernels bitcount,dotprod --memories 1024
     repro fuzz --arch 4x4,mesh-4x4,bordermem-4x4 --memories 10000 --shrink
     repro fuzz --kernels all --backend pallas --json --out results/fuzz.json
+    repro fuzz --kernels gsm --memories 16384 --trace fuzz-trace
+    repro trace report fuzz-trace     # where the fuzz run spent its time
 
 Each (kernel, arch) pair is mapped through a
 :class:`~repro.toolchain.session.Toolchain` (content-addressed cache
@@ -102,6 +104,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--json", action="store_true",
                     help="print the JSON digest instead of a summary")
     ap.add_argument("--out", default=None, help="also write the digest here")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="record an obs trace of the run into DIR; "
+                         "`repro trace report DIR` then shows where the "
+                         "fuzz run spent its time (decode, dispatch, "
+                         "device wait, transfer, oracle, harvest)")
     ap.add_argument("--strict", action="store_true",
                     help="also exit non-zero on unmapped/timed-out "
                          "kernels (default: only mismatches and engine "
@@ -109,6 +116,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "its mapping budget is a loudly-reported "
                          "coverage gap, not a correctness verdict)")
     args = ap.parse_args(argv)
+    if args.trace:
+        from ..obs import trace as obs_trace
+
+        obs_trace.enable(args.trace)
 
     from ..cgra.registry import ensure_registered
     from ..core.mapper import MapperConfig

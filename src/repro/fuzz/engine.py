@@ -30,6 +30,7 @@ import numpy as np
 from ..cgra.bitstream import AssembledCIL, assemble
 from ..cgra.isa import FXP_FRAC_BITS
 from ..cgra.programs import Carry, LoopBuilder, Val
+from ..obs import trace as obs_trace
 
 M32 = (1 << 32) - 1
 _SIGN = 1 << 31
@@ -315,52 +316,72 @@ def fuzz_program(
     each chunk in one PE-array dispatch, runs the batched oracle on the
     same chunk, and compares under the ``verify`` contract.  Activity
     statistics are harvested from the recorded out traces on the fly.
+
+    Spans (``repro.obs``): one ``verify.job`` per call, a ``verify.chunk``
+    per chunk holding the seam's ``verify.seam`` (see ``execute_asm``),
+    ``verify.nodes``, ``verify.transfer`` (the final memories to the
+    host, with ``d2h_bytes``), ``verify.oracle``, ``verify.compare`` and
+    ``verify.activity``.  ``exec_time_s`` and ``oracle_time_s`` are the
+    summed durations of the timed ``verify.seam`` and ``verify.oracle``.
     """
     from ..cgra.simulator import execute_asm
 
     from .activity import ActivityAccumulator
 
-    if asm is None:
-        asm = assemble(program, mapping)
-    mems = np.asarray(mems, np.int32)
-    if mems.ndim == 1:
-        mems = mems[None, :]
-    n = mems.shape[0]
-    rep = FuzzReport(kernel=kernel or program.name, arch=arch,
-                     status="ok", ii=asm.ii, memories=n,
-                     batch=min(batch, n) if n else batch, backend=backend)
-    acc = ActivityAccumulator(asm, mapping.grid) if collect_activity else None
-    t_exec = t_oracle = 0.0
-    t_total0 = time.monotonic()
-    for lo in range(0, n, batch):
-        chunk = mems[lo:lo + batch]
-        t0 = time.monotonic()
-        final, outs, _ = execute_asm(asm, mapping.grid, chunk,
-                                     batch=chunk.shape[0], backend=backend)
-        sim_vals = node_values_from_outs(asm, outs, program.trip)
-        sim_mem = np.asarray(final.mem)
-        t_exec += time.monotonic() - t0
-        t0 = time.monotonic()
-        oracle_vals, oracle_mem = batched_oracle(program, chunk)
-        t_oracle += time.monotonic() - t0
-        bad = compare_batch(sim_vals, sim_mem, oracle_vals, oracle_mem)
-        for i in np.nonzero(bad)[0]:
-            rep.failing.append(lo + int(i))
-            if len(rep.mismatches) < _MISMATCH_SAMPLE_CAP:
-                rep.mismatches.extend(mismatch_strings(
-                    program, sim_vals, sim_mem, oracle_vals, oracle_mem,
-                    int(i), label=lo + int(i))[:_MISMATCH_SAMPLE_CAP])
+    with obs_trace.span("verify.job", kernel=kernel or program.name,
+                        backend=backend) as job, \
+            obs_trace.tally() as seconds:
+        if asm is None:
+            asm = assemble(program, mapping)
+        mems = np.asarray(mems, np.int32)
+        if mems.ndim == 1:
+            mems = mems[None, :]
+        n = mems.shape[0]
+        rep = FuzzReport(kernel=kernel or program.name, arch=arch,
+                         status="ok", ii=asm.ii, memories=n,
+                         batch=min(batch, n) if n else batch,
+                         backend=backend)
+        job.set(memories=n, batch=rep.batch)
+        acc = (ActivityAccumulator(asm, mapping.grid) if collect_activity
+               else None)
+        t_total0 = time.monotonic()
+        for lo in range(0, n, batch):
+            chunk = mems[lo:lo + batch]
+            with obs_trace.span("verify.chunk", memories=chunk.shape[0],
+                                rows=len(asm.rows),
+                                pes=mapping.grid.num_pes):
+                final, outs, _ = execute_asm(asm, mapping.grid, chunk,
+                                             batch=chunk.shape[0],
+                                             backend=backend)
+                with obs_trace.span("verify.nodes"):
+                    sim_vals = node_values_from_outs(asm, outs, program.trip)
+                with obs_trace.span("verify.transfer") as sp:
+                    sim_mem = np.asarray(final.mem)
+                    sp.set(d2h_bytes=sim_mem.nbytes)
+                with obs_trace.timed_span("verify.oracle"):
+                    oracle_vals, oracle_mem = batched_oracle(program, chunk)
+                with obs_trace.span("verify.compare"):
+                    bad = compare_batch(sim_vals, sim_mem, oracle_vals,
+                                        oracle_mem)
+                for i in np.nonzero(bad)[0]:
+                    rep.failing.append(lo + int(i))
+                    if len(rep.mismatches) < _MISMATCH_SAMPLE_CAP:
+                        rep.mismatches.extend(mismatch_strings(
+                            program, sim_vals, sim_mem, oracle_vals,
+                            oracle_mem, int(i),
+                            label=lo + int(i))[:_MISMATCH_SAMPLE_CAP])
+                if acc is not None:
+                    with obs_trace.span("verify.activity"):
+                        acc.update(outs)
+        wall = time.monotonic() - t_total0
+        rep.exec_time_s = round(seconds["verify.seam"], 4)
+        rep.oracle_time_s = round(seconds["verify.oracle"], 4)
+        rep.mem_rate = round(n / wall, 2) if wall > 0 and n else 0.0
+        rep.mismatches = rep.mismatches[:_MISMATCH_SAMPLE_CAP]
+        if rep.failing:
+            rep.status = "mismatch"
         if acc is not None:
-            acc.update(outs)
-    wall = time.monotonic() - t_total0
-    rep.exec_time_s = round(t_exec, 4)
-    rep.oracle_time_s = round(t_oracle, 4)
-    rep.mem_rate = round(n / wall, 2) if wall > 0 and n else 0.0
-    rep.mismatches = rep.mismatches[:_MISMATCH_SAMPLE_CAP]
-    if rep.failing:
-        rep.status = "mismatch"
-    if acc is not None:
-        rep.activity = acc.report().to_dict()
+            rep.activity = acc.report().to_dict()
     return rep
 
 

@@ -21,6 +21,7 @@ from .trace import (
     event,
     shipping_context,
     span,
+    tally,
     timed_span,
     trace_dir,
 )
@@ -36,6 +37,7 @@ __all__ = [
     "event",
     "shipping_context",
     "span",
+    "tally",
     "timed_span",
     "trace_dir",
 ]

@@ -17,7 +17,13 @@ When tracing is disabled, :func:`span` returns a shared no-op singleton
 and writes nothing — the fast path is one global check. The toolchain's
 stage timers use :func:`timed_span`, which still measures duration when
 disabled (so ``CompileResult.timings`` stays populated) but never
-touches the sink.
+touches the sink. :func:`tally` sums such durations by name over a block
+(how ``FuzzReport.exec_time_s`` reads the execution seam).
+
+When tracing is enabled and JAX is already imported, a span entered with
+``with`` is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+a running profiler trace shows the program's spans on the clock of the
+device's operations. This module never imports JAX itself.
 
 Record schema (``SCHEMA_VERSION == 1``)::
 
@@ -35,10 +41,13 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
+from collections import defaultdict
+from contextlib import contextmanager
 from contextvars import ContextVar
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional
 
 SCHEMA_VERSION = 1
 
@@ -53,6 +62,8 @@ _sink = None
 _sink_pid: Optional[int] = None
 
 _current: ContextVar[Optional["Span"]] = ContextVar("repro_obs_span", default=None)
+_tally: ContextVar[Optional[Dict[str, float]]] = ContextVar(
+    "repro_obs_tally", default=None)
 
 
 def _new_id() -> str:
@@ -140,6 +151,7 @@ class Span:
         "dur",
         "_token",
         "_done",
+        "_annotation",
     )
 
     def __init__(
@@ -166,6 +178,7 @@ class Span:
         self.dur = 0.0
         self._token = None
         self._done = False
+        self._annotation = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes; must happen before the span finishes."""
@@ -200,6 +213,7 @@ class Span:
         if attrs:
             self.attrs.update(attrs)
         self.dur = time.monotonic() - self.t0
+        _count(self.name, self.dur)
         _write(
             {
                 "v": SCHEMA_VERSION,
@@ -219,9 +233,16 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -267,9 +288,10 @@ class _Timer:
     """Duration-only span substitute used by :func:`timed_span` when
     tracing is off — measures ``dur`` but never touches the sink."""
 
-    __slots__ = ("t0", "dur")
+    __slots__ = ("name", "t0", "dur")
 
-    def __init__(self) -> None:
+    def __init__(self, name: str) -> None:
+        self.name = name
         self.t0 = 0.0
         self.dur = 0.0
 
@@ -285,6 +307,7 @@ class _Timer:
     def finish(self, **attrs: Any) -> "_Timer":
         if self.dur == 0.0:
             self.dur = time.monotonic() - self.t0
+            _count(self.name, self.dur)
         return self
 
     def __enter__(self) -> "_Timer":
@@ -293,7 +316,27 @@ class _Timer:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur = time.monotonic() - self.t0
+        _count(self.name, self.dur)
         return False
+
+
+def _count(name: str, dur: float) -> None:
+    totals = _tally.get()
+    if totals is not None:
+        totals[name] += dur
+
+
+@contextmanager
+def tally() -> Iterator[Dict[str, float]]:
+    """Sum, by name, the durations of the spans that close inside the
+    block (the innermost ``tally`` only). With tracing off only the
+    spans of :func:`timed_span` are measured, so read only those."""
+    totals: Dict[str, float] = defaultdict(float)
+    token = _tally.set(totals)
+    try:
+        yield totals
+    finally:
+        _tally.reset(token)
 
 
 def span(name: str, parent: Optional[Dict[str, str]] = None, **attrs: Any):
@@ -316,7 +359,7 @@ def timed_span(name: str, **attrs: Any):
     duration-only timer instead of the no-op singleton. The toolchain's
     stage timing (``CompileResult.timings``) is a projection of these."""
     if not _enabled:
-        return _Timer()
+        return _Timer(name)
     return Span(name, attrs)
 
 
