@@ -2,7 +2,7 @@
 
 The static energy model (``repro.cgra.energy``) charges every executed op
 its full per-op energy — implicitly assuming reference switching activity
-on the operand and result buses.  This module replays the recorded out
+on the operand and result buses.  This module reads the recorded out
 traces of a batched run through the *routing* datapath (operand selectors
 + register file + neighbor wiring — no ALU re-execution needed, the
 results are the trace) and measures what actually toggled:
@@ -14,6 +14,15 @@ results are the trace) and measures what actually toggled:
 * operand-bus toggle rates: same statistic on the A/B port values each
   executed op actually latched.
 
+Nothing the routing datapath tracks depends on the data: whether a cell
+executes is its opcode, the register it writes back is its ``dst``, and
+every selector is a static code.  So every value a port latches is a
+fixed cell of the OUT trace, a preset, an immediate or zero, and every
+toggle statistic is ``popcount(V[i] ^ V[j])`` summed over the batch, for
+index pairs ``(i, j)`` into one value array ``V`` that are worked out
+once per schedule (:func:`replay_tables`).  Harvesting a chunk is then
+one gather, XOR and popcount over those pairs.
+
 ``repro.cgra.energy.runtime_metrics(activity=...)`` turns these into an
 empirical dynamic-energy estimate: each op's energy scales with its
 measured toggle rate relative to the reference rate
@@ -22,14 +31,15 @@ measured toggle rate relative to the reference rate
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, Optional
+from dataclasses import dataclass
+from itertools import chain
+from typing import Dict
 
 import numpy as np
 
 from ..cgra.arch import PEGrid
 from ..cgra.bitstream import AssembledCIL
-from ..cgra.isa import OPCODE, OPS, SRC_IMM, SRC_OWN, SRC_ZERO
+from ..cgra.isa import OPCODE, OPS, SRC_IMM, SRC_N, SRC_OWN, SRC_W, SRC_ZERO
 
 M32 = (1 << 32) - 1
 
@@ -40,6 +50,9 @@ except AttributeError:                        # pragma: no cover - old numpy
     _POP_TABLE = np.array([bin(i).count("1") for i in range(256)],
                           np.uint8)
 
+# gathered elements (pairs x memories) per block of a chunk's harvest
+_BLOCK_ELEMS = 1 << 21
+
 
 def popcount_u32(x: np.ndarray) -> np.ndarray:
     """Per-element popcount of a uint32 array."""
@@ -49,9 +62,109 @@ def popcount_u32(x: np.ndarray) -> np.ndarray:
     return _POP_TABLE[b].reshape(x.shape + (4,)).sum(-1).astype(np.int64)
 
 
-def _xor_bits(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Hamming distance between int64-held int32 values, elementwise."""
-    return popcount_u32((((a ^ b) & M32)).astype(np.uint32))
+def _row_bits(x: np.ndarray) -> np.ndarray:
+    """(n, B) uint32 -> (n,) int64: set bits of each row."""
+    if _np_bitcount is not None:
+        return _np_bitcount(x).sum(axis=1, dtype=np.int64)
+    return popcount_u32(x).sum(axis=1)      # pragma: no cover
+
+
+@dataclass(frozen=True)
+class ReplayTables:
+    """Static index pairs of one schedule's toggle statistics.
+
+    A chunk's value array ``V`` (rows, B) holds the OUT-trace cells
+    ``(cell_t, cell_p)`` that some pair reads, then the constants
+    ``consts`` (presets, immediates, zero) that some pair reads.  Each
+    distinct pair ``pairs[u]`` indexes two rows of ``V``; contribution
+    ``e`` adds ``pairs[pair_of[e]]``'s set bits to bin ``target[e]``: the
+    op code for a result, ``len(OPS)`` + the op code for an operand.
+    """
+
+    cell_t: np.ndarray
+    cell_p: np.ndarray
+    consts: np.ndarray
+    pairs: np.ndarray
+    pair_of: np.ndarray
+    target: np.ndarray
+
+
+def _last_before(mask: np.ndarray) -> np.ndarray:
+    """(T, ...) bool -> (T, ...) int64: the last row before each row in
+    which ``mask`` held, else -1."""
+    rows = np.arange(len(mask)).reshape((-1,) + (1,) * (mask.ndim - 1))
+    last = np.maximum.accumulate(np.where(mask, rows, -1), axis=0)
+    return np.concatenate([np.full_like(last[:1], -1), last[:-1]])
+
+
+def replay_tables(op: np.ndarray, dst: np.ndarray, sa: np.ndarray,
+                  sb: np.ndarray, imm: np.ndarray, nbr: np.ndarray,
+                  out0: np.ndarray, regs0: np.ndarray) -> ReplayTables:
+    """Replay the routing datapath's timeline on slot indices.
+
+    op/dst/sa/sb/imm (T, P), nbr (P, 4) N/E/S/W, out0 (P,), regs0 (P, 4).
+    Selection reads the state before row t; the OUT and the latched A/B
+    advance only on executed cells, and register ``k`` takes the row's
+    result where the cell executed and ``dst == k``, as in
+    ``repro.kernels.ref``.  Each of those is the OUT-trace cell of the
+    last row before t that wrote it, or its preset, so the whole
+    timeline is a running maximum over the rows.
+    """
+    T, P = op.shape
+    n_trace = T * P
+    imm_u = imm & M32
+    imms = np.unique(imm_u)
+    consts = np.concatenate([out0 & M32, regs0.ravel() & M32, imms,
+                             [0]]).astype(np.uint32)
+    base = n_trace + 5 * P
+    zero = base + len(imms)
+
+    pe = np.arange(P)
+    executed = op != 0
+    # (T, P) the row each cell's OUT, and (T, P, 4) each register, last
+    # took a result before row t; -1 reads the preset
+    out_row = _last_before(executed)
+    reg_row = _last_before(executed[:, :, None]
+                           & (dst[:, :, None] == np.arange(4)))
+    prev_out = np.where(out_row >= 0, out_row * P + pe, n_trace + pe)
+    regs = np.where(reg_row >= 0, reg_row * P + pe[:, None],
+                    n_trace + P + 4 * pe[:, None] + np.arange(4))
+    cands = np.empty((T, P, 11), np.int64)
+    cands[:, :, :4] = regs
+    cands[:, :, SRC_OWN] = prev_out
+    cands[:, :, SRC_N:SRC_W + 1] = prev_out[:, nbr]
+    cands[:, :, SRC_IMM] = base + np.searchsorted(imms, imm_u)
+    cands[:, :, SRC_ZERO] = zero
+    # (T, P) each cell's latched A and B, then the ones latched at the
+    # PE's last executed row before t
+    a = np.take_along_axis(cands, sa[:, :, None], axis=2)[:, :, 0]
+    b = np.take_along_axis(cands, sb[:, :, None], axis=2)[:, :, 0]
+    latched = np.maximum(out_row, 0)
+    prev_a = np.where(out_row >= 0,
+                      np.take_along_axis(a, latched, axis=0), zero)
+    prev_b = np.where(out_row >= 0,
+                      np.take_along_axis(b, latched, axis=0), zero)
+    cur = np.arange(n_trace).reshape(T, P)
+
+    # each executed cell's three pairs, its result against the previous
+    # OUT and its A and B against the previous A and B; a pair of one
+    # slot never toggles
+    n_ops = len(OPS)
+    ops = op[executed]
+    lo = np.concatenate([cur[executed], a[executed], b[executed]])
+    hi = np.concatenate([prev_out[executed], prev_a[executed],
+                         prev_b[executed]])
+    target = np.concatenate([ops, n_ops + ops, n_ops + ops])
+    moves = lo != hi
+    lo, hi = np.minimum(lo, hi)[moves], np.maximum(lo, hi)[moves]
+    keys, pair_of = np.unique(lo * (zero + 1) + hi, return_inverse=True)
+    # V holds only the slots the distinct pairs read, in slot order
+    slots, rows = np.unique(np.divmod(keys, zero + 1), return_inverse=True)
+    cells = slots[slots < n_trace]
+    return ReplayTables(cell_t=cells // P, cell_p=cells % P,
+                        consts=consts[slots[len(cells):] - n_trace],
+                        pairs=rows.reshape(2, -1).T,
+                        pair_of=pair_of.ravel(), target=target[moves])
 
 
 @dataclass
@@ -83,9 +196,10 @@ class ActivityAccumulator:
 
     One accumulator per assembled kernel; call :meth:`update` with each
     chunk's out trace (T, B, P) and read :meth:`report` at the end.
-    The operand replay mirrors ``repro.kernels.ref.select_operand``
-    exactly (register file timeline included), so the harvested values
-    are the values the ALU ports actually saw.
+    The slot pairs come from :func:`replay_tables`, which mirrors
+    ``repro.kernels.ref.select_operand`` exactly (register file timeline
+    included), so the harvested values are the values the ALU ports
+    actually saw.  Each accumulator builds its schedule's tables once.
     """
 
     def __init__(self, asm: AssembledCIL, grid: PEGrid):
@@ -95,74 +209,54 @@ class ActivityAccumulator:
         rows = asm.rows
         T, P = len(rows), asm.num_pes
         self.T, self.P = T, P
-        self.op = np.array([[OPCODE[ins.op] for ins in row]
-                            for row in rows], np.int64)
-        self.dst = np.array([[ins.dst for ins in row] for row in rows],
-                            np.int64)
-        self.sa = np.array([[ins.src_a for ins in row] for row in rows],
-                           np.int64)
-        self.sb = np.array([[ins.src_b for ins in row] for row in rows],
-                           np.int64)
-        self.imm = np.array([[ins.imm for ins in row] for row in rows],
-                            np.int64)
-        self.nbr = np.asarray(neighbor_table(grid), np.int64)  # (P, 4)
+        fields = np.fromiter(chain.from_iterable(
+            (OPCODE[ins.op], ins.dst, ins.src_a, ins.src_b, ins.imm)
+            for row in rows for ins in row), np.int64, count=T * P * 5)
+        fields = fields.reshape(T, P, 5)
+        nbr = np.asarray(neighbor_table(grid), np.int64)   # (P, 4)
         out0 = np.zeros(P, np.int64)
         regs0 = np.zeros((P, 4), np.int64)
         for pe, val in asm.presets_out.items():
             out0[pe] = np.int64(np.int32(val))
         for (pe, r), val in asm.presets_reg.items():
             regs0[pe, r] = np.int64(np.int32(val))
-        self._out0, self._regs0 = out0, regs0
+        self.tables = replay_tables(*np.moveaxis(fields, 2, 0), nbr, out0,
+                                    regs0)
         n_ops = len(OPS)
-        self._cells_per_op = np.bincount(self.op.ravel(), minlength=n_ops)
+        self._cells_per_op = np.bincount(fields[:, :, 0].ravel(),
+                                         minlength=n_ops)
         self._res_bits = np.zeros(n_ops, np.int64)
         self._opnd_bits = np.zeros(n_ops, np.int64)
         self._memories = 0
 
-    def _select(self, sel: np.ndarray, regs: np.ndarray, out: np.ndarray,
-                imm_row: np.ndarray) -> np.ndarray:
-        """sel (P,), regs (B, P, 4), out (B, P) -> chosen operand (B, P),
-        source order matching the ISA selector codes."""
-        B, P = out.shape
-        cands = np.empty((11, B, P), np.int64)
-        for k in range(4):
-            cands[k] = regs[:, :, k]
-        cands[SRC_OWN] = out
-        for k in range(4):                       # N, E, S, W
-            cands[SRC_OWN + 1 + k] = out[:, self.nbr[:, k]]
-        cands[SRC_IMM] = np.broadcast_to(imm_row, (B, P))
-        cands[SRC_ZERO] = 0
-        return cands[sel, :, np.arange(P)].T     # (B, P)
-
     def update(self, outs: np.ndarray) -> None:
         """Fold one chunk's out trace (T, B, P) into the statistics."""
-        outs = _wrap_trace(outs)
-        T, B, P = outs.shape
+        x = np.asarray(outs)
+        T, B, P = x.shape
         if (T, P) != (self.T, self.P):
             raise ValueError(
                 f"trace shape ({T}, ., {P}) does not match the schedule "
                 f"({self.T}, ., {self.P})")
-        prev_out = np.broadcast_to(self._out0, (B, P)).copy()
-        regs = np.broadcast_to(self._regs0, (B, P, 4)).copy()
-        prev_a = np.zeros((B, P), np.int64)
-        prev_b = np.zeros((B, P), np.int64)
-        for t in range(T):
-            executed = self.op[t] != 0                        # (P,)
-            a = self._select(self.sa[t], regs, prev_out, self.imm[t])
-            b = self._select(self.sb[t], regs, prev_out, self.imm[t])
-            res = outs[t]
-            tog_res = _xor_bits(res, prev_out).sum(axis=0) * executed
-            tog_opnd = (_xor_bits(a, prev_a) + _xor_bits(b, prev_b)) \
-                .sum(axis=0) * executed
-            np.add.at(self._res_bits, self.op[t], tog_res)
-            np.add.at(self._opnd_bits, self.op[t], tog_opnd)
-            exec_b = executed[None, :]
-            prev_out = np.where(exec_b, res, prev_out)
-            prev_a = np.where(exec_b, a, prev_a)
-            prev_b = np.where(exec_b, b, prev_b)
-            for k in range(4):
-                hit = exec_b & (self.dst[t] == k)[None, :]
-                regs[:, :, k] = np.where(hit, res, regs[:, :, k])
+        x = (x.view(np.uint32) if x.dtype in (np.int32, np.uint32)
+             else x.astype(np.uint32))
+        tables = self.tables
+        n_cells = len(tables.cell_t)
+        values = np.empty((n_cells + len(tables.consts), B), np.uint32)
+        values[:n_cells] = x[tables.cell_t, :, tables.cell_p]
+        values[n_cells:] = tables.consts[:, None]
+        pairs = tables.pairs
+        bits = np.empty(len(pairs), np.int64)
+        step = max(1, _BLOCK_ELEMS // max(B, 1))
+        for lo in range(0, len(pairs), step):
+            block = pairs[lo:lo + step]
+            diff = values[block[:, 0]]
+            diff ^= values[block[:, 1]]
+            bits[lo:lo + step] = _row_bits(diff)
+        n_ops = len(OPS)
+        totals = np.zeros(2 * n_ops, np.int64)
+        np.add.at(totals, tables.target, bits[tables.pair_of])
+        self._res_bits += totals[:n_ops]
+        self._opnd_bits += totals[n_ops:]
         self._memories += B
 
     def report(self) -> ActivityReport:
@@ -185,11 +279,6 @@ class ActivityAccumulator:
             kernel=self.asm.name, memories=self._memories, cycles=self.T,
             op_exec=op_exec, result_toggle=result_toggle,
             operand_toggle=operand_toggle)
-
-
-def _wrap_trace(outs) -> np.ndarray:
-    x = np.asarray(np.asarray(outs), np.int64) & M32
-    return x - ((x >= (1 << 31)).astype(np.int64) << 32)
 
 
 def harvest_activity(asm: AssembledCIL, grid: PEGrid,
