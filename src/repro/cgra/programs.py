@@ -256,14 +256,18 @@ def stringsearch(trip: int = 16) -> LoopBuilder:
     return p
 
 
-def gsm(trip: int = 16) -> LoopBuilder:
-    """Saturating fixed-point multiply-accumulate (paper: 14 nodes / 20 edges)."""
+def gsm(trip: int = 16, x_base: int = 0, y_base: int = 32,
+        out_base: int = 64) -> LoopBuilder:
+    """Saturating fixed-point multiply-accumulate (paper: 14 nodes / 20 edges).
+
+    Reads ``x[i]`` at ``x_base + i`` and ``y[i]`` at ``y_base + i`` and
+    stores the saturated running sum at ``out_base + i + 1``."""
     MAX, MIN = 32767, -32768
     p = LoopBuilder("gsm", trip)
     i = p.carry("i", 0)
     acc = p.carry("acc", 0)
-    x = p.op("LWI", i, None, imm=0)
-    y = p.op("LWI", i, None, imm=32)
+    x = p.op("LWI", i, None, imm=x_base)
+    y = p.op("LWI", i, None, imm=y_base)
     prod = p.op("SMUL", x, y)
     sh = p.op("SRA", prod, None, imm=15)
     s = p.op("SADD", acc, sh)
@@ -272,7 +276,7 @@ def gsm(trip: int = 16) -> LoopBuilder:
     cmin = p.op("SSUB", Val(s1.node), None, imm=MIN)  # sign => s1 < MIN
     s2 = p.op("BSFA", None, s1, imm=MIN, flag=cmin)
     i2 = p.op("SADD", i, None, imm=1)
-    p.op("SWI", i2, s2, imm=64)
+    p.op("SWI", i2, s2, imm=out_base)
     t = p.op("BNE", i2, None, imm=trip)
     p.op("JUMP", t)
     p.set_carry(i, i2)
@@ -372,6 +376,36 @@ BENCHMARKS = {
 }
 
 
+#: the samples of one GSM 06.10 speech segment (20 ms at 8 kHz, ETSI EN
+#: 300 961) and the power-of-two data memory above gsm_frame's three arrays
+FRAME_SAMPLES = 160
+FRAME_MEM_WORDS = 512
+
+
+def gsm_frame() -> LoopBuilder:
+    """gsm's loop at the length of a whole speech segment: ``x`` at 0,
+    ``y`` at 160, the running sums at 321..480 of a 512-word memory.
+
+    This is gsm's MAC run for 160 samples, not a routine of the codec:
+    GSM 06.10's loops of this shape (Q15 products summed with saturation)
+    run over 40-sample subframes, and its 160-sample autocorrelation sums
+    in 32 bits."""
+    return gsm(FRAME_SAMPLES, 0, FRAME_SAMPLES, 2 * FRAME_SAMPLES)
+
+
+def gsm_frame_mem(seed: int = 0):
+    """Randomized 512-word input image for :func:`gsm_frame`: both
+    160-sample arrays in gsm's ``[-2**14, 2**14)``."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    mem = np.zeros(FRAME_MEM_WORDS, np.int32)
+    mem[0:FRAME_SAMPLES] = rng.randint(-(2**14), 2**14, FRAME_SAMPLES)
+    mem[FRAME_SAMPLES:2 * FRAME_SAMPLES] = rng.randint(
+        -(2**14), 2**14, FRAME_SAMPLES)
+    return mem
+
+
 def benchmark_mem(name: str, seed: int = 0):
     """Randomized 128-word input image for a Table-6 benchmark.
 
@@ -405,6 +439,9 @@ def _register_benchmarks() -> None:
             name, factory, origin="handwritten",
             make_mem=functools.partial(benchmark_mem, name),
             tags=("table6",))
+    register_kernel("gsm_frame", gsm_frame, origin="handwritten",
+                    make_mem=gsm_frame_mem, tags=("frame",),
+                    variant_of="gsm")
 
 
 _register_benchmarks()

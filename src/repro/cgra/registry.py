@@ -16,11 +16,14 @@ workload meant editing every sweep site.  Now there is a single registry:
 Each entry carries the kernel *factory* (a fresh
 :class:`~repro.cgra.programs.LoopBuilder` per call) plus the randomized
 input-memory generator used by end-to-end execution and differential
-co-simulation.
+co-simulation.  A *variant* (``variant_of`` set) is another kernel's loop
+at a deployment's trip count and memory layout; it is fetched by name but
+left out of :func:`kernel_names`' suite unless asked for.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
@@ -50,6 +53,12 @@ class KernelSpec:
     origin: str  # "handwritten" | "traced"
     make_mem: Callable[[int], np.ndarray] = _default_mem  # seed -> (M,) int32
     tags: Tuple[str, ...] = field(default_factory=tuple)
+    variant_of: Optional[str] = None  # the kernel whose loop this one runs
+
+    @functools.cached_property
+    def mem_words(self) -> int:
+        """M, the words of every image ``make_mem`` returns."""
+        return len(self.make_mem(0))
 
 
 _REGISTRY: Dict[str, KernelSpec] = {}
@@ -63,6 +72,7 @@ def register_kernel(
     origin: str,
     make_mem: Optional[Callable[[int], np.ndarray]] = None,
     tags: Tuple[str, ...] = (),
+    variant_of: Optional[str] = None,
     replace: bool = False,
 ) -> KernelSpec:
     if origin not in ORIGINS:
@@ -71,7 +81,8 @@ def register_kernel(
         raise ValueError(f"kernel {name!r} already registered "
                          f"(origin={_REGISTRY[name].origin})")
     spec = KernelSpec(name=name, factory=factory, origin=origin,
-                      make_mem=make_mem or _default_mem, tags=tuple(tags))
+                      make_mem=make_mem or _default_mem, tags=tuple(tags),
+                      variant_of=variant_of)
     _REGISTRY[name] = spec
     return spec
 
@@ -94,15 +105,19 @@ def get_kernel(name: str) -> KernelSpec:
     ensure_registered()
     if name not in _REGISTRY:
         raise KeyError(
-            f"unknown kernel {name!r}; registered: {kernel_names()}")
+            f"unknown kernel {name!r}; registered: "
+            f"{kernel_names(variants=True)}")
     return _REGISTRY[name]
 
 
-def kernel_names(origin: Optional[str] = None) -> List[str]:
-    """Registration-ordered kernel names, optionally filtered by origin."""
+def kernel_names(origin: Optional[str] = None,
+                 variants: bool = False) -> List[str]:
+    """Registration-ordered kernel names, optionally filtered by origin;
+    variants only with ``variants``."""
     ensure_registered()
     return [n for n, s in _REGISTRY.items()
-            if origin is None or s.origin == origin]
+            if (origin is None or s.origin == origin)
+            and (variants or s.variant_of is None)]
 
 
 def kernel_factories(origin: Optional[str] = None) -> Dict[str, Callable]:

@@ -36,7 +36,7 @@ def _resolve_kernels(spec: str) -> List[str]:
     if spec == "all":
         return kernel_names()
     names = [k.strip() for k in spec.split(",") if k.strip()]
-    known = set(kernel_names())
+    known = set(kernel_names(variants=True))
     unknown = [k for k in names if k not in known]
     if unknown:
         raise SystemExit(f"unknown kernel(s): {', '.join(unknown)} "
@@ -52,7 +52,8 @@ def _print_human(rep: FuzzReport) -> None:
         return
     verdict = "ok" if rep.ok else f"MISMATCH ({len(rep.failing)} memories)"
     print(f"{head}: {verdict}  II={rep.ii}  {rep.memories} memories "
-          f"@ {rep.mem_rate:.0f} mem/s (batch {rep.batch}, {rep.backend})")
+          f"of {rep.mem_words} words @ {rep.mem_rate:.0f} mem/s "
+          f"(batch {rep.batch}, {rep.backend})")
     if rep.energy:
         e = rep.energy
         print(f"  dynamic energy: static {e['static_dynamic_nj']} nJ -> "

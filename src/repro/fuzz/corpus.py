@@ -2,8 +2,8 @@
 
 Every registry kernel declares (or implies) the memory regions it reads;
 the corpus fills exactly those regions under five strategies and leaves
-the rest of the 128-word image zero, matching the registered
-``make_mem`` layout:
+the rest of the image zero, matching the registered ``make_mem`` layout
+and size (``MEM_SIZE`` words, or ``gsm_frame``'s 512):
 
 * ``uniform``  — every region cell uniform in its declared ``[lo, hi)``
 * ``boundary`` — region bounds, ±1, 0 and the 16-bit immediate extremes
@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..cgra.isa import IMM_MAX, IMM_MIN
+from ..cgra.registry import get_kernel
 
 INT32_MIN = -(1 << 31)
 INT32_MAX = (1 << 31) - 1
@@ -63,6 +64,8 @@ _HANDWRITTEN_REGIONS: Dict[str, Tuple[Region, ...]] = {
                      Region(48, 16, 0, 8)),
     "gsm": (Region(0, 16, -(2 ** 14), 2 ** 14),
             Region(32, 16, -(2 ** 14), 2 ** 14)),
+    "gsm_frame": (Region(0, 160, -(2 ** 14), 2 ** 14),
+                  Region(160, 160, -(2 ** 14), 2 ** 14)),
 }
 _DEFAULT_REGIONS: Tuple[Region, ...] = (Region(0, 32, 0, 2 ** 30),)
 
@@ -70,8 +73,6 @@ _DEFAULT_REGIONS: Tuple[Region, ...] = (Region(0, 32, 0, 2 ** 30),)
 @functools.lru_cache(maxsize=None)
 def kernel_regions(name: str) -> Tuple[Region, ...]:
     """The randomized input regions of one registry kernel."""
-    from ..cgra.registry import get_kernel
-
     spec = get_kernel(name)
     if spec.origin == "traced":
         from ..frontend.kernels import TRACED_KERNELS
@@ -114,8 +115,10 @@ def _fill_regions(mem: np.ndarray, regions: Sequence[Region],
 
 def generate_memory(kernel: str, index: int, seed: int = 0,
                     strategy: Optional[str] = None,
-                    mem_size: int = MEM_SIZE) -> np.ndarray:
-    """One deterministic (mem_size,) int32 image for corpus slot ``index``."""
+                    mem_size: Optional[int] = None) -> np.ndarray:
+    """One deterministic (mem_size,) int32 image for corpus slot ``index``;
+    ``mem_size`` defaults to the kernel's own memory size."""
+    mem_size = mem_size or get_kernel(kernel).mem_words
     strategy = strategy or STRATEGIES[index % len(STRATEGIES)]
     if strategy not in STRATEGIES:
         raise ValueError(f"unknown corpus strategy {strategy!r}; "
@@ -163,8 +166,10 @@ def generate_memory(kernel: str, index: int, seed: int = 0,
 
 def make_corpus(kernel: str, n: int, seed: int = 0,
                 strategies: Optional[Sequence[str]] = None,
-                mem_size: int = MEM_SIZE) -> np.ndarray:
-    """(n, mem_size) int32 corpus; row ``i`` uses strategy ``i % len``."""
+                mem_size: Optional[int] = None) -> np.ndarray:
+    """(n, mem_size) int32 corpus; row ``i`` uses strategy ``i % len``.
+    ``mem_size`` defaults to the kernel's own memory size."""
+    mem_size = mem_size or get_kernel(kernel).mem_words
     chosen = tuple(strategies) if strategies else STRATEGIES
     for s in chosen:
         if s not in STRATEGIES:

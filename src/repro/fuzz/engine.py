@@ -274,6 +274,7 @@ class FuzzReport:
     status: str                      # ok | mismatch | unmapped | timeout | error
     ii: Optional[int] = None
     memories: int = 0
+    mem_words: int = 0               # words of each memory
     batch: int = 0
     backend: str = "ref"
     failing: List[int] = field(default_factory=list)   # corpus indices
@@ -317,10 +318,12 @@ def fuzz_program(
     same chunk, and compares under the ``verify`` contract.  Activity
     statistics are harvested from the recorded out traces on the fly.
 
-    Spans (``repro.obs``): one ``verify.job`` per call, a ``verify.chunk``
-    per chunk holding the seam's ``verify.seam`` (see ``execute_asm``),
-    ``verify.nodes``, ``verify.transfer`` (the final memories to the
-    host, with ``d2h_bytes``), ``verify.oracle``, ``verify.compare`` and
+    Spans (``repro.obs``): one ``verify.job`` per call (with the loop's
+    ``trip`` and the memories' ``mem_words``), a ``verify.chunk`` per
+    chunk (with its ``rows``, ``pes`` and ``mem_words``) holding the
+    seam's ``verify.seam`` (see ``execute_asm``), ``verify.nodes``,
+    ``verify.transfer`` (the final memories to the host, with
+    ``d2h_bytes``), ``verify.oracle``, ``verify.compare`` and
     ``verify.activity``.  ``exec_time_s`` and ``oracle_time_s`` are the
     summed durations of the timed ``verify.seam`` and ``verify.oracle``.
     """
@@ -339,9 +342,11 @@ def fuzz_program(
         n = mems.shape[0]
         rep = FuzzReport(kernel=kernel or program.name, arch=arch,
                          status="ok", ii=asm.ii, memories=n,
+                         mem_words=mems.shape[1],
                          batch=min(batch, n) if n else batch,
                          backend=backend)
-        job.set(memories=n, batch=rep.batch)
+        job.set(memories=n, batch=rep.batch, trip=program.trip,
+                mem_words=mems.shape[1])
         acc = (ActivityAccumulator(asm, mapping.grid) if collect_activity
                else None)
         t_total0 = time.monotonic()
@@ -349,7 +354,8 @@ def fuzz_program(
             chunk = mems[lo:lo + batch]
             with obs_trace.span("verify.chunk", memories=chunk.shape[0],
                                 rows=len(asm.rows),
-                                pes=mapping.grid.num_pes):
+                                pes=mapping.grid.num_pes,
+                                mem_words=chunk.shape[1]):
                 final, outs, _ = execute_asm(asm, mapping.grid, chunk,
                                              batch=chunk.shape[0],
                                              backend=backend)

@@ -237,10 +237,12 @@ def _cmd_arch_show(args) -> int:
 def _cmd_list(args) -> int:
     from ..cgra.registry import get_kernel, kernel_names
 
-    names = kernel_names(origin=args.origin or None)
+    names = kernel_names(origin=args.origin or None, variants=True)
     for name in names:
         spec = get_kernel(name)
-        print(f"{name:16s} {spec.origin}")
+        variant = (f" (variant of {spec.variant_of})" if spec.variant_of
+                   else "")
+        print(f"{name:16s} {spec.origin}{variant}")
     print(f"{len(names)} kernels")
     return 0
 

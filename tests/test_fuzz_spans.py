@@ -107,11 +107,14 @@ def test_span_tree_parentage_and_one_trace(compiled, traced):
     assert parents == ["verify.chunk"] * 2 + ["verify.seam"] * 2
     job = next(r for r in spans.values() if r["name"] == "verify.job")
     assert job["attrs"] == {"kernel": KERNEL, "memories": MEMORIES,
-                            "batch": BATCH, "backend": "ref"}
+                            "batch": BATCH, "backend": "ref",
+                            "trip": cr.program.builder.trip,
+                            "mem_words": 128}
     for r in spans.values():
         if r["name"] == "verify.chunk":
             assert r["attrs"] == {"memories": BATCH,
-                                  "rows": len(cr.asm.rows), "pes": 16}
+                                  "rows": len(cr.asm.rows), "pes": 16,
+                                  "mem_words": 128}
 
 
 def test_d2h_bytes_match_the_formula(compiled, traced):

@@ -4,9 +4,10 @@ described, not attached.
 ``run_program`` with the ref scan and with the compiled Pallas cycle step,
 at the fabric sizes the verification fleet runs (4x4 and 6x6 torus), a
 chunk of 8,192 memories of 128 words (the registry's memory size) and a
-ragged last chunk.  Nothing runs: a pass says that the chip's compiler
-accepts the programs and that the Pallas step fits its scoped VMEM, not
-that results are right (tests/test_kernels.py checks those in interpret
+ragged last chunk; and gsm_frame's 804 rows (gsm at trip 160) over
+8,192 memories of 512 words on the 4x4 torus.  Nothing runs: a pass says
+that the chip's compiler accepts the programs and that the Pallas step
+fits its scoped VMEM, not that results are right (tests/test_kernels.py checks those in interpret
 mode on the CPU).
 
 The topology is described inside a fixture, never at import: only one
@@ -24,6 +25,8 @@ from repro.kernels.ref import InstrRow, PEState  # noqa: E402
 
 ROWS = 112          # stencil3's bitstream on the 4x4 torus, the longest smoked
 MEM_WORDS = 128     # cgra/programs.py benchmark_mem
+FRAME_ROWS = 804    # gsm_frame's bitstream on the 4x4 torus (trip 160)
+FRAME_MEM_WORDS = 512
 HBM_BYTES = 16 * 2**30
 
 
@@ -56,7 +59,8 @@ def one_chip():
             compilation_cache.reset_cache()
 
 
-def _compile(one_chip, monkeypatch, n, batch, backend):
+def _compile(one_chip, monkeypatch, n, batch, backend, rows=ROWS,
+             mem_words=MEM_WORDS):
     # the program asks the default backend (here the CPU) whether to
     # interpret; this chip is described, so steer it to the compiled step
     monkeypatch.setattr(pe_array, "interpret_mode", lambda: False)
@@ -66,10 +70,10 @@ def _compile(one_chip, monkeypatch, n, batch, backend):
     def spec(shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    fields = InstrRow(*(spec((ROWS, P)) for _ in range(5)))
+    fields = InstrRow(*(spec((rows, P)) for _ in range(5)))
     state = PEState(regs=spec((batch, P, 4)), out=spec((batch, P)),
                     sf=spec((batch, P)), zf=spec((batch, P)),
-                    mem=spec((batch, MEM_WORDS)))
+                    mem=spec((batch, mem_words)))
     return ops.run_program.lower(fields, state, neighbor_table(grid),
                                  backend=backend).compile()
 
@@ -88,6 +92,16 @@ def test_pallas_step_compiles_for_v5e(one_chip, monkeypatch, n, batch):
 def test_ref_scan_compiles_for_v5e(one_chip, monkeypatch, n):
     compiled = _compile(one_chip, monkeypatch, n, 8192, "ref")
     assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_frame_program_compiles_for_v5e(one_chip, monkeypatch, backend):
+    compiled = _compile(one_chip, monkeypatch, 4, 8192, backend,
+                        rows=FRAME_ROWS, mem_words=FRAME_MEM_WORDS)
+    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
