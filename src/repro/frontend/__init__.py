@@ -36,8 +36,8 @@ Known gaps
   per kernel; ``bool(traced value)`` raises :class:`TraceError`
 * **division / modulo** — no divider in the ISA
 * **fxpmul operand range** — the reference computes the exact wide
-  product, but the JAX PE-array evaluates FXPMUL in int32 when x64 is
-  disabled (the default): keep ``|a*b| < 2**31`` (bound your
+  product, but the JAX PE-array evaluates FXPMUL on the int32
+  product: keep ``|a*b| < 2**31`` (bound your
   ``MemRegion`` ranges accordingly, as ``ema_fxp`` does) or the co-sim
   will report the wrap as a mismatch
 * **memory aliasing** — the DFG carries no memory-ordering edges (same as

@@ -51,8 +51,8 @@ def eval_binop(op: str, a: int, b: int) -> int:
     if op == "mul":
         return s32(a * b)
     if op == "fxpmul":
-        # exact wide product, matching the Python oracle.  The JAX ref
-        # backend computes this in int32 unless x64 is enabled, so traced
+        # exact wide product, matching the Python oracle.  The JAX
+        # PE-array computes this on the int32 product, so traced
         # kernels must keep |a*b| < 2**31 (see the fxpmul gap note in
         # repro.frontend.__init__) or co-simulation will flag the wrap.
         return s32((s32(a) * s32(b)) >> FXP_FRAC_BITS)

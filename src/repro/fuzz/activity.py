@@ -197,7 +197,7 @@ class ActivityAccumulator:
     One accumulator per assembled kernel; call :meth:`update` with each
     chunk's out trace (T, B, P) and read :meth:`report` at the end.
     The slot pairs come from :func:`replay_tables`, which mirrors
-    ``repro.kernels.ref.select_operand`` exactly (register file timeline
+    ``repro.kernels.ref.operand`` exactly (register file timeline
     included), so the harvested values are the values the ALU ports
     actually saw.  Each accumulator builds its schedule's tables once.
     """

@@ -21,7 +21,7 @@ Addresses in every registry kernel derive from induction carries, never
 from loaded data, so adversarial *values* cannot push addressing out of
 bounds.  The one value-range guard: kernels containing FXPMUL get their
 extremes clipped into the declared region range, because the JAX ref
-backend computes the Q16.16 product in int32 (x64 disabled) while the
+backend computes the Q16.16 product in int32 while the
 oracle computes it exactly — outside the declared range that is a known
 front-end gap (see ``repro.frontend.ir.eval_binop``), not a mapping bug.
 """

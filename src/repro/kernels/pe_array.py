@@ -8,18 +8,17 @@ blocks of OUT and the flags (batch rows on sublanes, the P PEs on lanes), a
 ``(bt, P, 4)`` register block and a ``(bt, M)`` data-memory block (memory
 words on lanes).
 
-Two deliberate deviations from a literal port:
-* neighbor OUT reads use *static* slicing (the torus is compile-time
-  constant), so no dynamic gather is emitted;
-* data-memory load/store uses one-hot masking against the (bt, M) memory
-  tile instead of scattered addressing — exactly equivalent for in-range
-  addresses (benchmark memories are 128-256 words).
+Neighbour reads, operand selection and the ALU are ref.py's own functions
+(static column slices and compare-and-select chains).  Loads and stores
+use one (bt, P, M) one-hot mask against the memory tile, where the ref step
+loops over the PEs on (B, M): the two agree on every cycle the module
+contract defines, and the mask sums the values of same-cycle stores to one
+address, which the contract leaves undefined.
 
 The kernel runs compiled on an accelerator and interpreted on the CPU
 backend (:func:`interpret_mode`).  tests/test_kernels.py checks it against
 kernels/ref.py in interpret mode; tests/test_tpu_compile.py compiles it for
-a TPU v5e.  FXPMUL uses int32 here vs int64 in the oracle, so tests restrict
-FXPMUL operands to the non-overflowing range.
+a TPU v5e.
 """
 from __future__ import annotations
 
@@ -28,11 +27,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-from ..cgra.isa import FXP_FRAC_BITS, OPCODE
-from .ref import InstrRow, PEState
+from ..cgra.isa import OPCODE
+from .ref import InstrRow, PEState, alu, neighbor_outs, operand
 
 B_TILE = 128  # most batch rows per grid step
 # Largest (rows, P, M) one-hot mask a grid step may hold, in int32 words,
@@ -66,38 +64,6 @@ def padded_batch(batch: int, num_pes: int, mem_words: int) -> int:
     return batch if batch <= bt else -(-batch // bt) * bt
 
 
-def _alu_block(op, a, b, sf, zf):
-    """Vectorized all-op ALU on a (bt, P) block (int32)."""
-    shift = b & 31
-    prod = a * b
-
-    def sel(name, val, acc):
-        return jnp.where(op[None, :] == OPCODE[name], val, acc)
-
-    acc = jnp.zeros_like(a)
-    acc = sel("SADD", a + b, acc)
-    acc = sel("MOV", a + b, acc)
-    acc = sel("SSUB", a - b, acc)
-    acc = sel("SMUL", prod, acc)
-    acc = sel("FXPMUL", prod >> FXP_FRAC_BITS, acc)   # int32 (see docstring)
-    acc = sel("SLT", a << shift, acc)
-    acc = sel("SRT", jax.lax.shift_right_logical(a, shift), acc)
-    acc = sel("SRA", jax.lax.shift_right_arithmetic(a, shift), acc)
-    acc = sel("LAND", a & b, acc)
-    acc = sel("LOR", a | b, acc)
-    acc = sel("LXOR", a ^ b, acc)
-    acc = sel("LNAND", ~(a & b), acc)
-    acc = sel("LNOR", ~(a | b), acc)
-    acc = sel("LXNOR", ~(a ^ b), acc)
-    acc = sel("BSFA", jnp.where(sf > 0, a, b), acc)
-    acc = sel("BZFA", jnp.where(zf > 0, a, b), acc)
-    for name in ("BEQ", "BNE", "BLT", "BGE"):
-        acc = sel(name, a - b, acc)
-    for name in ("SWD", "SWI"):
-        acc = sel(name, b, acc)
-    return acc
-
-
 def _cycle_kernel(neighbors: Tuple[Tuple[int, int, int, int], ...],
                   op_ref, dst_ref, sa_ref, sb_ref, imm_ref,
                   regs_ref, out_ref, sf_ref, zf_ref, mem_ref,
@@ -115,28 +81,10 @@ def _cycle_kernel(neighbors: Tuple[Tuple[int, int, int, int], ...],
     B, P = out.shape
     M = mem.shape[1]
 
-    # neighbor OUT columns via static permutation (torus is compile-time)
-    nbr = np.asarray(neighbors)  # (P, 4)
-    out_nbr = [
-        jnp.concatenate([out[:, int(nbr[p, k])][:, None] for p in range(P)],
-                        axis=1)
-        for k in range(4)
-    ]
-
-    def operand(sel):
-        selb = sel[None, :]
-        val = jnp.zeros((B, P), jnp.int32)
-        for idx in range(4):
-            val = jnp.where(selb == idx, regs[:, :, idx], val)
-        val = jnp.where(selb == 4, out, val)
-        for k in range(4):
-            val = jnp.where(selb == 5 + k, out_nbr[k], val)
-        val = jnp.where(selb == 9, imm[None, :].astype(jnp.int32), val)
-        return val
-
-    a = operand(sa)
-    b = operand(sb)
-    res = _alu_block(op, a, b, sf, zf)
+    out_nbr = neighbor_outs(out, neighbors)
+    a = operand(sa, regs, out, out_nbr, imm)
+    b = operand(sb, regs, out, out_nbr, imm)
+    res = alu(op, a, b, sf, zf)
 
     is_lwi = op == OPCODE["LWI"]
     is_load = (op == OPCODE["LWD"]) | is_lwi

@@ -5,23 +5,33 @@ instruction row and the PE-array state, advance one CGRA-cycle.  All integer
 ALU ops are int32 with wrap-around; flags (sign/zero) are per-PE and updated
 by every executed non-NOP op; BSFA/BZFA select between their operands based
 on the *pre-cycle* flags (i.e. the flags of the previous instruction on that
-PE, as in OpenEdgeCGRA).
+PE, as in OpenEdgeCGRA).  FXPMUL is ``(a * b) >> FXP_FRAC_BITS`` on the
+int32 product (the high bits of the 64-bit product are lost).  A selector
+that names no source (SRC_ZERO, or a code above it) reads zero.
+
+The step is dense: operands, ALU result, loads and stores are each chosen
+by elementwise compare-and-select over values the step already holds (a
+selector, an opcode or a word index), so it compiles to no gather and no
+scatter.  :func:`neighbor_outs`, :func:`operand` and :func:`alu` are the
+Pallas step's too.
 
 Contract: two simultaneous stores to the same address in one cycle are
 undefined behaviour (real hardware serializes them through the column port;
-the mapper never schedules them) — the ref scatter and the Pallas one-hot
-store may disagree only in that case.
+the mapper never schedules them) — the step here keeps the value of the
+highest-numbered PE, the Pallas one-hot store their sum, and the two may
+disagree only in that case.
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+import functools
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..cgra.isa import (FXP_FRAC_BITS, OPCODE, SRC_E, SRC_IMM, SRC_N,
-                        SRC_OWN, SRC_S, SRC_W, SRC_ZERO)
+                        SRC_OWN, SRC_R0, SRC_S, SRC_W)
 
 
 class PEState(NamedTuple):
@@ -40,57 +50,56 @@ class InstrRow(NamedTuple):
     imm: jax.Array    # (P,) int32
 
 
-def select_operand(sel, regs, out, out_nbr, imm):
+def neighbor_outs(out, neighbors):
+    """OUT of every PE's (N, E, S, W) neighbour: four (B, P) arrays built
+    from static column slices, since the topology is compile-time."""
+    nbr = np.asarray(neighbors)
+    return [jnp.concatenate([out[:, q:q + 1] for q in nbr[:, k]], axis=1)
+            for k in range(4)]
+
+
+def operand(sel, regs, out, out_nbr, imm):
     """sel: (P,), state tensors batched (B, ...). Returns (B, P) int32."""
-    B, P = out.shape
-    cands = jnp.stack([
-        regs[:, :, 0], regs[:, :, 1], regs[:, :, 2], regs[:, :, 3],
-        out,
-        out_nbr[0], out_nbr[1], out_nbr[2], out_nbr[3],   # N, E, S, W
-        jnp.broadcast_to(imm[None, :], (B, P)),
-        jnp.zeros((B, P), jnp.int32),
-    ], axis=-1)                                            # (B, P, 11)
-    sel_b = jnp.broadcast_to(sel[None, :, None], (B, P, 1))
-    return jnp.take_along_axis(cands, sel_b, axis=-1)[..., 0]
+    sel = sel[None, :]
+    val = jnp.zeros_like(out)
+    for k in range(4):
+        val = jnp.where(sel == SRC_R0 + k, regs[:, :, k], val)
+    val = jnp.where(sel == SRC_OWN, out, val)
+    for k, src in enumerate((SRC_N, SRC_E, SRC_S, SRC_W)):
+        val = jnp.where(sel == src, out_nbr[k], val)
+    return jnp.where(sel == SRC_IMM, imm[None, :], val)
 
 
 def alu(op, a, b, sf, zf):
-    """All-op ALU with select-by-opcode. op: (P,), a/b/sf/zf: (B, P)."""
+    """All-op ALU, selected by opcode. op: (P,), a/b/sf/zf: (B, P).  Loads
+    read zero here (the memory path replaces them), as do NOP, JUMP and
+    EXIT."""
     shift = b & 31
-    a64 = a.astype(jnp.int64)
-    b64 = b.astype(jnp.int64)
-    results = {
-        "NOP": jnp.zeros_like(a),
-        "SADD": a + b,
-        "SSUB": a - b,
-        "SMUL": a * b,
-        "FXPMUL": ((a64 * b64) >> FXP_FRAC_BITS).astype(jnp.int32),
-        "SLT": a << shift,
-        "SRT": jax.lax.shift_right_logical(a, shift),
-        "SRA": jax.lax.shift_right_arithmetic(a, shift),
-        "LAND": a & b,
-        "LOR": a | b,
-        "LXOR": a ^ b,
-        "LNAND": ~(a & b),
-        "LNOR": ~(a | b),
-        "LXNOR": ~(a ^ b),
-        "BSFA": jnp.where(sf > 0, a, b),
-        "BZFA": jnp.where(zf > 0, a, b),
-        "LWD": a,            # placeholder: replaced by the memory path
-        "LWI": a,
-        "SWD": b,            # result of a store is the stored value
-        "SWI": b,
-        "BEQ": a - b,
-        "BNE": a - b,
-        "BLT": a - b,
-        "BGE": a - b,
-        "JUMP": jnp.zeros_like(a),
-        "EXIT": jnp.zeros_like(a),
-        "MOV": a + b,
-    }
-    stacked = jnp.stack([results[name] for name in OPCODE], axis=-1)
-    op_b = jnp.broadcast_to(op[None, :, None], a.shape + (1,))
-    return jnp.take_along_axis(stacked, op_b, axis=-1)[..., 0]
+    prod = a * b
+    results = (
+        (("SADD", "MOV"), a + b),
+        (("SSUB", "BEQ", "BNE", "BLT", "BGE"), a - b),
+        (("SMUL",), prod),
+        (("FXPMUL",), prod >> FXP_FRAC_BITS),
+        (("SLT",), a << shift),
+        (("SRT",), jax.lax.shift_right_logical(a, shift)),
+        (("SRA",), jax.lax.shift_right_arithmetic(a, shift)),
+        (("LAND",), a & b),
+        (("LOR",), a | b),
+        (("LXOR",), a ^ b),
+        (("LNAND",), ~(a & b)),
+        (("LNOR",), ~(a | b)),
+        (("LXNOR",), ~(a ^ b)),
+        (("BSFA",), jnp.where(sf > 0, a, b)),
+        (("BZFA",), jnp.where(zf > 0, a, b)),
+        (("SWD", "SWI"), b),          # a store's result is the stored value
+    )
+    acc = jnp.zeros_like(a)
+    for names, val in results:
+        hit = functools.reduce(jnp.logical_or,
+                               [op == OPCODE[n] for n in names])
+        acc = jnp.where(hit[None, :], val, acc)
+    return acc
 
 
 def cycle_step_ref(state: PEState, instr: InstrRow,
@@ -98,10 +107,9 @@ def cycle_step_ref(state: PEState, instr: InstrRow,
     """One CGRA-cycle. ``neighbors[p] = (N, E, S, W)`` is static topology."""
     regs, out, sf, zf, mem = state
     B, P = out.shape
-    nbr = np.asarray(neighbors)                            # (P, 4) static
-    out_nbr = [out[:, nbr[:, k]] for k in range(4)]
-    a = select_operand(instr.sa, regs, out, out_nbr, instr.imm)
-    b = select_operand(instr.sb, regs, out, out_nbr, instr.imm)
+    out_nbr = neighbor_outs(out, neighbors)
+    a = operand(instr.sa, regs, out, out_nbr, instr.imm)
+    b = operand(instr.sb, regs, out, out_nbr, instr.imm)
 
     res = alu(instr.op, a, b, sf, zf)
 
@@ -113,11 +121,15 @@ def cycle_step_ref(state: PEState, instr: InstrRow,
     M = mem.shape[1]
     addr = a + jnp.where((is_lwi | is_swi)[None, :], instr.imm[None, :], 0)
     addr_c = jnp.clip(addr, 0, M - 1)
-    loaded = jnp.take_along_axis(mem, addr_c, axis=1)
+    # one PE at a time over (B, M): the word index against the PE's address
+    words = jax.lax.broadcasted_iota(jnp.int32, (B, M), 1)
+    hits = [words == addr_c[:, p:p + 1] for p in range(P)]
+    loaded = jnp.concatenate(
+        [jnp.sum(jnp.where(hit, mem, 0), axis=1, keepdims=True)
+         for hit in hits], axis=1)
     res = jnp.where(is_load[None, :], loaded, res)
-
-    store_addr = jnp.where(is_store[None, :], addr_c, M)   # M = dropped
-    mem = mem.at[jnp.arange(B)[:, None], store_addr].set(b, mode="drop")
+    for p, hit in enumerate(hits):
+        mem = jnp.where(hit & is_store[p], b[:, p:p + 1], mem)
 
     executed = (instr.op != OPCODE["NOP"])[None, :]
     out = jnp.where(executed, res, out)

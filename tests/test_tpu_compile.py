@@ -6,8 +6,9 @@ at the fabric sizes the verification fleet runs (4x4 and 6x6 torus), a
 chunk of 8,192 memories of 128 words (the registry's memory size) and a
 ragged last chunk; and gsm_frame's 804 rows (gsm at trip 160) over
 8,192 memories of 512 words on the 4x4 torus.  Nothing runs: a pass says
-that the chip's compiler accepts the programs and that the Pallas step
-fits its scoped VMEM, not that results are right (tests/test_kernels.py checks those in interpret
+that the chip's compiler accepts the programs, that the Pallas step fits
+its scoped VMEM and that the ref scan holds no gather and no scatter, not
+that results are right (tests/test_kernels.py checks those in interpret
 mode on the CPU).
 
 The topology is described inside a fixture, never at import: only one
@@ -105,3 +106,16 @@ def test_frame_program_compiles_for_v5e(one_chip, monkeypatch, backend):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("n,mem_words", [(4, MEM_WORDS), (6, MEM_WORDS),
+                                         (4, FRAME_MEM_WORDS)])
+def test_ref_scan_holds_no_gather_or_scatter(one_chip, monkeypatch, n,
+                                             mem_words):
+    """The ref step picks operands, ALU results, loaded words and stored
+    words by compare-and-select: on the chip an index gather or scatter in
+    the scan body costs per index, not per byte (PERF.md section 5)."""
+    hlo = _compile(one_chip, monkeypatch, n, 8192, "ref",
+                   mem_words=mem_words).as_text()
+    assert "gather(" not in hlo
+    assert "scatter(" not in hlo
