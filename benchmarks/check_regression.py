@@ -96,10 +96,10 @@ SERVING_TIME = ("throughput_rps", "p50_ms", "p99_ms", "wall_time_s")
 # mapping, bit-exact oracle): per-pair status/II/failing indices, the
 # energy delta and the first-divergence record are all hard; only the
 # memories/sec rates ride the wall clock
-FUZZ_HARD = ("status", "ii", "memories", "batch", "backend", "failing",
-             "energy", "divergence")
-FUZZ_TOP_HARD = ("archs", "kernels", "memories", "batch", "backend",
-                 "seed", "mismatches", "errors", "unmapped")
+FUZZ_HARD = ("status", "ii", "memories", "batch", "failing", "energy",
+             "divergence")
+FUZZ_TOP_HARD = ("archs", "kernels", "memories", "batch", "seed",
+                 "mismatches", "errors", "unmapped")
 FUZZ_TIME = ("map_time_s", "exec_time_s", "oracle_time_s", "mem_rate")
 FUZZTP_HARD = ("status", "ii", "arch", "memories", "batch", "failing",
                "verdict_match", "stacked_failing",

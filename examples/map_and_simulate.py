@@ -2,10 +2,10 @@
 
 Maps the bitcount benchmark on a 2x2 OpenEdgeCGRA, assembles the
 prologue/kernel/epilogue control words, executes them cycle-accurately on
-the JAX PE-array simulator (Pallas kernel optional), and checks the result
+the JAX PE-array simulator, and checks the result
 against the Python oracle.
 
-  PYTHONPATH=src python examples/map_and_simulate.py [--backend pallas]
+  PYTHONPATH=src python examples/map_and_simulate.py [--benchmark NAME]
 """
 import argparse
 
@@ -22,7 +22,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--benchmark", default="bitcount",
                     choices=sorted(BENCHMARKS))
-    ap.add_argument("--backend", default="ref", choices=["ref", "pallas"])
     ap.add_argument("--size", type=int, default=2)
     args = ap.parse_args()
 
@@ -37,7 +36,7 @@ def main():
           f"first kernel words: "
           f"{[hex(w) for w in asm.kernel_words()[0][:4]]}")
     mem = np.zeros(128, np.int32)
-    sim = simulate(prog, res.mapping, mem, batch=1, backend=args.backend)
+    sim = simulate(prog, res.mapping, mem, batch=1)
     oracle = prog.run_oracle([0] * 128)
     for name, nid in prog.result_nodes.items():
         got = int(sim.node_values[nid][0])

@@ -1,7 +1,7 @@
 """Cycle-accurate execution of mapped CILs + end-to-end verification.
 
 Pipeline: LoopBuilder program -> SAT mapping -> bitstream -> JAX PE-array
-execution (ref or Pallas backend) -> per-node value extraction.  The
+execution (the jnp cycle step, scanned) -> per-node value extraction.  The
 ``verify`` helper compares every node's last-iteration value and the final
 data memory against the pure-Python oracle — the strongest possible check of
 schedule, routing, register allocation and codegen at once.
@@ -103,6 +103,9 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
     ``verify.preset``, ``verify.dispatch`` (trace, compile on a miss,
     enqueue), ``verify.wait`` (the device run) and ``verify.transfer``
     (the OUT trace to the host); ``d2h_bytes`` counts what comes back."""
+    # kept only for the seam fakes of tests/bench/test_bench_faults.py
+    if backend != "ref":
+        raise ValueError(f"unknown simulator backend {backend!r}")
     import jax
 
     from ..kernels.ops import decode_fields, run_program
@@ -117,7 +120,7 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
         out0 = np.array(state.out)
         nbrs = neighbor_table(grid)
         with obs_trace.span("verify.dispatch"):
-            final, outs = run_program(fields, state, nbrs, backend=backend)
+            final, outs = run_program(fields, state, nbrs)
         with obs_trace.span("verify.wait"):
             jax.block_until_ready((final, outs))
         with obs_trace.span("verify.transfer") as sp:
@@ -127,10 +130,9 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
 
 
 def simulate(program: LoopBuilder, mapping: Mapping, mem: np.ndarray,
-             batch: int = 1, backend: str = "ref") -> SimResult:
+             batch: int = 1) -> SimResult:
     asm = assemble(program, mapping)
-    final, outs, _ = execute_asm(asm, mapping.grid, mem, batch=batch,
-                                 backend=backend)
+    final, outs, _ = execute_asm(asm, mapping.grid, mem, batch=batch)
     node_values: Dict[int, np.ndarray] = {}
     last_iter = program.trip - 1
     for (t, pe), (n, j) in asm.node_of_cell.items():
@@ -141,12 +143,12 @@ def simulate(program: LoopBuilder, mapping: Mapping, mem: np.ndarray,
                      total_rows=len(asm.rows))
 
 
-def verify(program: LoopBuilder, mapping: Mapping, mem: np.ndarray,
-           backend: str = "ref") -> List[str]:
+def verify(program: LoopBuilder, mapping: Mapping,
+           mem: np.ndarray) -> List[str]:
     """Returns a list of mismatch strings (empty == end-to-end correct)."""
     errors: List[str] = []
     mem = np.asarray(mem, np.int32)
-    sim = simulate(program, mapping, mem, batch=1, backend=backend)
+    sim = simulate(program, mapping, mem, batch=1)
     oracle_mem = [int(v) for v in mem]
     program_copy = program  # oracle mutates mem list only
     results = program_copy.run_oracle(oracle_mem)

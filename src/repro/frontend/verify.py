@@ -77,7 +77,7 @@ class CoSimReport:
 
 
 def cosimulate(tk, rows: int = 4, cols: int = 4, seeds: int = 16,
-               config: Optional[MapperConfig] = None, backend: str = "ref",
+               config: Optional[MapperConfig] = None,
                execute: bool = True) -> CoSimReport:
     """Map one traced kernel and (optionally) execute it against the
     reference over ``seeds`` randomized inputs; see the module docstring."""
@@ -105,7 +105,7 @@ def cosimulate(tk, rows: int = 4, cols: int = 4, seeds: int = 16,
 
     mems = np.stack([tk.make_mem(seed) for seed in range(seeds)])
     # the session's simulate stage needs the jax extra
-    sim = tc.simulate(art, res.mapping, mems, batch=seeds, backend=backend)
+    sim = tc.simulate(art, res.mapping, mems, batch=seeds)
     rep.seeds = seeds
     # one vectorized reference run over the whole seed batch (the old
     # per-seed python_reference loop, retired by repro.fuzz); mismatch
@@ -132,7 +132,7 @@ def cosimulate(tk, rows: int = 4, cols: int = 4, seeds: int = 16,
 
 def run_all(kernels: Optional[Sequence[str]] = None, rows: int = 4,
             cols: int = 4, seeds: int = 16,
-            config: Optional[MapperConfig] = None, backend: str = "ref",
+            config: Optional[MapperConfig] = None,
             execute: bool = True) -> Dict:
     """Co-simulate every (or the named) traced kernels; JSON-ready doc."""
     from .kernels import TRACED_KERNELS
@@ -144,8 +144,7 @@ def run_all(kernels: Optional[Sequence[str]] = None, rows: int = 4,
                        f"available: {sorted(TRACED_KERNELS)}")
     t0 = time.monotonic()
     reports = [cosimulate(TRACED_KERNELS[n], rows=rows, cols=cols,
-                          seeds=seeds, config=config, backend=backend,
-                          execute=execute)
+                          seeds=seeds, config=config, execute=execute)
                for n in names]
     return {
         "bench": "frontend_cosim",
@@ -173,8 +172,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--kernels", default="",
                     help="comma-separated subset (default: all traced)")
     ap.add_argument("--out", default="results/frontend_cosim.json")
-    ap.add_argument("--backend", default="ref",
-                    choices=("ref", "pallas"), help="simulator backend")
     ap.add_argument("--map-only", action="store_true",
                     help="skip execution (no jax needed): map + II bound")
     ap.add_argument("--timeout", type=float, default=120.0,

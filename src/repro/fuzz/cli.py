@@ -4,7 +4,7 @@ Examples::
 
     repro fuzz --kernels bitcount,dotprod --memories 1024
     repro fuzz --arch 4x4,mesh-4x4,bordermem-4x4 --memories 10000 --shrink
-    repro fuzz --kernels all --backend pallas --json --out results/fuzz.json
+    repro fuzz --kernels all --json --out results/fuzz.json
     repro fuzz --kernels gsm --memories 16384 --trace fuzz-trace
     repro trace report fuzz-trace     # where the fuzz run spent its time
 
@@ -53,7 +53,7 @@ def _print_human(rep: FuzzReport) -> None:
     verdict = "ok" if rep.ok else f"MISMATCH ({len(rep.failing)} memories)"
     print(f"{head}: {verdict}  II={rep.ii}  {rep.memories} memories "
           f"of {rep.mem_words} words @ {rep.mem_rate:.0f} mem/s "
-          f"(batch {rep.batch}, {rep.backend})")
+          f"(batch {rep.batch})")
     if rep.energy:
         e = rep.energy
         print(f"  dynamic energy: static {e['static_dynamic_nj']} nJ -> "
@@ -84,8 +84,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="corpus size per (kernel, arch) (default 1024)")
     ap.add_argument("--batch", type=int, default=1024,
                     help="memories per PE-array dispatch (default 1024)")
-    ap.add_argument("--backend", default="ref", choices=["ref", "pallas"],
-                    help="simulator backend (default ref)")
     ap.add_argument("--seed", type=int, default=0,
                     help="corpus base seed (default 0)")
     ap.add_argument("--strategies", default=None,
@@ -138,7 +136,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         for name in kernels:
             rep = fuzz_kernel(
                 name, arch=arch, memories=args.memories, batch=args.batch,
-                backend=args.backend, seed=args.seed, shrink=args.shrink,
+                seed=args.seed, shrink=args.shrink,
                 config=cfg, cache=args.cache_dir,
                 failures_dir=args.failures_dir, strategies=strategies)
             reports.append(rep)
@@ -151,7 +149,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "kernels": kernels,
         "memories": args.memories,
         "batch": args.batch,
-        "backend": args.backend,
         "seed": args.seed,
         "results": [r.to_dict() for r in reports],
         "mismatches": sum(1 for r in reports if r.status == "mismatch"),

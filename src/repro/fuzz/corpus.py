@@ -20,8 +20,8 @@ and platforms (``hash()`` is salted, so it is never used here).
 Addresses in every registry kernel derive from induction carries, never
 from loaded data, so adversarial *values* cannot push addressing out of
 bounds.  The one value-range guard: kernels containing FXPMUL get their
-extremes clipped into the declared region range, because the JAX ref
-backend computes the Q16.16 product in int32 while the
+extremes clipped into the declared region range, because the JAX cycle
+step computes the Q16.16 product in int32 while the
 oracle computes it exactly — outside the declared range that is a known
 front-end gap (see ``repro.frontend.ir.eval_binop``), not a mapping bug.
 """
@@ -86,7 +86,7 @@ def kernel_regions(name: str) -> Tuple[Region, ...]:
 @functools.lru_cache(maxsize=None)
 def uses_wide_product(name: str) -> bool:
     """Whether the kernel's program contains FXPMUL (the one op whose
-    ref-backend int32 product diverges from the exact oracle outside the
+    cycle step's int32 product diverges from the exact oracle outside the
     declared input range)."""
     from ..cgra.registry import kernel_program
 

@@ -276,7 +276,6 @@ class FuzzReport:
     memories: int = 0
     mem_words: int = 0               # words of each memory
     batch: int = 0
-    backend: str = "ref"
     failing: List[int] = field(default_factory=list)   # corpus indices
     mismatches: List[str] = field(default_factory=list)  # capped sample
     error: Optional[str] = None
@@ -305,7 +304,6 @@ def fuzz_program(
     mapping,
     mems: np.ndarray,
     batch: int = 1024,
-    backend: str = "ref",
     collect_activity: bool = True,
     asm: Optional[AssembledCIL] = None,
     kernel: Optional[str] = None,
@@ -331,8 +329,7 @@ def fuzz_program(
 
     from .activity import ActivityAccumulator
 
-    with obs_trace.span("verify.job", kernel=kernel or program.name,
-                        backend=backend) as job, \
+    with obs_trace.span("verify.job", kernel=kernel or program.name) as job, \
             obs_trace.tally() as seconds:
         if asm is None:
             asm = assemble(program, mapping)
@@ -343,8 +340,7 @@ def fuzz_program(
         rep = FuzzReport(kernel=kernel or program.name, arch=arch,
                          status="ok", ii=asm.ii, memories=n,
                          mem_words=mems.shape[1],
-                         batch=min(batch, n) if n else batch,
-                         backend=backend)
+                         batch=min(batch, n) if n else batch)
         job.set(memories=n, batch=rep.batch, trip=program.trip,
                 mem_words=mems.shape[1])
         acc = (ActivityAccumulator(asm, mapping.grid) if collect_activity
@@ -357,8 +353,7 @@ def fuzz_program(
                                 pes=mapping.grid.num_pes,
                                 mem_words=chunk.shape[1]):
                 final, outs, _ = execute_asm(asm, mapping.grid, chunk,
-                                             batch=chunk.shape[0],
-                                             backend=backend)
+                                             batch=chunk.shape[0])
                 with obs_trace.span("verify.nodes"):
                     sim_vals = node_values_from_outs(asm, outs, program.trip)
                 with obs_trace.span("verify.transfer") as sp:
@@ -396,7 +391,6 @@ def fuzz_kernel(
     arch: str = "4x4",
     memories: int = 1024,
     batch: int = 1024,
-    backend: str = "ref",
     seed: int = 0,
     shrink: bool = False,
     config=None,
@@ -432,13 +426,13 @@ def fuzz_kernel(
                           map_time_s=map_time)
     mems = make_corpus(name, memories, seed=seed, strategies=strategies)
     rep = fuzz_program(prog.builder, res.mapping, mems, batch=batch,
-                       backend=backend, kernel=name, arch=arch_name)
+                       kernel=name, arch=arch_name)
     rep.map_time_s = map_time
     if rep.activity is not None:
         rep.energy = _energy_delta(prog.builder, res.mapping, rep.activity)
     if rep.failing and shrink:
         triage_failure(prog.builder, res.mapping, mems, rep,
-                       backend=backend, out_dir=failures_dir)
+                       out_dir=failures_dir)
     return rep
 
 
@@ -493,7 +487,6 @@ def run_stacked(
     asms: Sequence[AssembledCIL],
     grid,
     mems: np.ndarray,
-    backend: str = "ref",
 ):
     """Execute K same-grid bitstreams over (K, B, M) memories in one
     ``vmap``-ed dispatch.  Returns (final PEState with a leading K axis,
@@ -528,7 +521,7 @@ def run_stacked(
     nbrs = neighbor_table(grid)
 
     def run_one(f, s):
-        return run_program(f, s, nbrs, backend=backend)
+        return run_program(f, s, nbrs)
 
     final, outs = jax.vmap(run_one)(stacked_fields, stacked_state)
     return final, np.asarray(outs)
@@ -538,7 +531,6 @@ def fuzz_stacked(
     programs: Sequence[LoopBuilder],
     mappings: Sequence,
     mems: np.ndarray,
-    backend: str = "ref",
     arch: str = "4x4",
 ) -> List[FuzzReport]:
     """Differentially fuzz K same-grid kernels in one stacked dispatch.
@@ -550,7 +542,7 @@ def fuzz_stacked(
     if mems.ndim == 2:
         mems = np.broadcast_to(mems[None], (len(asms),) + mems.shape)
     t0 = time.monotonic()
-    final, outs = run_stacked(asms, grid, mems, backend=backend)
+    final, outs = run_stacked(asms, grid, mems)
     exec_time = time.monotonic() - t0
     reports: List[FuzzReport] = []
     for k, (program, asm) in enumerate(zip(programs, asms)):
@@ -563,7 +555,6 @@ def fuzz_stacked(
         rep = FuzzReport(
             kernel=program.name, arch=arch, status="ok", ii=asm.ii,
             memories=int(mems.shape[1]), batch=int(mems.shape[1]),
-            backend=backend,
             exec_time_s=round(exec_time / len(asms), 4),
             oracle_time_s=round(oracle_time, 4))
         share = exec_time / len(asms) + oracle_time

@@ -13,7 +13,7 @@ bulk verdict into something a human can debug:
   per-iteration oracle values, naming the first (cycle, PE, node,
   iteration) where simulation and oracle part ways.
 * :func:`write_reproducer` — a self-contained JSON under
-  ``results/fuzz_failures/``: kernel, arch, II, backend, the memory
+  ``results/fuzz_failures/``: kernel, arch, II, the memory
   image, the divergence, and the verify-style mismatch lines.
 * :func:`inject_fault` — the detector's own self-test: flip one
   instruction field of a known-good bitstream so tests can prove the
@@ -117,7 +117,6 @@ def shrink(
 def engine_check(
     program: LoopBuilder,
     mapping,
-    backend: str = "ref",
     asm: Optional[AssembledCIL] = None,
 ) -> Callable[[np.ndarray], np.ndarray]:
     """The standard batched probe for :func:`shrink`: execute + oracle +
@@ -133,7 +132,7 @@ def engine_check(
         if mems.ndim == 1:
             mems = mems[None, :]
         final, outs, _ = execute_asm(the_asm, mapping.grid, mems,
-                                     batch=mems.shape[0], backend=backend)
+                                     batch=mems.shape[0])
         sim_vals = node_values_from_outs(the_asm, outs, program.trip)
         oracle_vals, oracle_mem = batched_oracle(program, mems)
         return compare_batch(sim_vals, np.asarray(final.mem),
@@ -146,7 +145,6 @@ def first_divergence(
     program: LoopBuilder,
     mapping,
     mem: np.ndarray,
-    backend: str = "ref",
     asm: Optional[AssembledCIL] = None,
 ) -> Optional[Divergence]:
     """Replay one memory with the full trace and name the first cell
@@ -157,8 +155,7 @@ def first_divergence(
     if asm is None:
         asm = assemble(program, mapping)
     mem = np.asarray(mem, np.int32).reshape(1, -1)
-    _, outs, _ = execute_asm(asm, mapping.grid, mem, batch=1,
-                             backend=backend)
+    _, outs, _ = execute_asm(asm, mapping.grid, mem, batch=1)
     history = batched_oracle_iterations(program, mem)
     for (t, pe) in sorted(asm.node_of_cell):
         n, j = asm.node_of_cell[(t, pe)]
@@ -175,7 +172,6 @@ def write_reproducer(
     kernel: str,
     arch: str,
     asm: AssembledCIL,
-    backend: str,
     mem: np.ndarray,
     corpus_index: int,
     divergence: Optional[Divergence],
@@ -193,7 +189,6 @@ def write_reproducer(
         "arch": arch,
         "ii": asm.ii,
         "trip": asm.trip,
-        "backend": backend,
         "corpus_index": corpus_index,
         "mem": [int(v) for v in np.asarray(mem).ravel()],
         "divergence": divergence.to_dict() if divergence else None,
@@ -209,7 +204,6 @@ def triage_failure(
     mapping,
     mems: np.ndarray,
     rep,
-    backend: str = "ref",
     out_dir: str = "results/fuzz_failures",
     asm: Optional[AssembledCIL] = None,
 ) -> None:
@@ -218,23 +212,23 @@ def triage_failure(
     reproducer, and annotate the report in place."""
     if asm is None:
         asm = assemble(program, mapping)
-    check = engine_check(program, mapping, backend=backend, asm=asm)
+    check = engine_check(program, mapping, asm=asm)
     failing = np.asarray(rep.failing, int)
     mem, idx, _probes = shrink(np.asarray(mems)[failing], check,
                                indices=failing)
-    div = first_divergence(program, mapping, mem, backend=backend, asm=asm)
+    div = first_divergence(program, mapping, mem, asm=asm)
     final_sim = check(mem.reshape(1, -1))  # noqa: F841 — warm replay
     from ..cgra.simulator import execute_asm
 
     final, outs, _ = execute_asm(asm, mapping.grid, mem.reshape(1, -1),
-                                 batch=1, backend=backend)
+                                 batch=1)
     sim_vals = node_values_from_outs(asm, outs, program.trip)
     oracle_vals, oracle_mem = batched_oracle(program, mem.reshape(1, -1))
     lines = mismatch_strings(program, sim_vals, np.asarray(final.mem),
                              oracle_vals, oracle_mem, 0, label=idx)
     rep.divergence = div.to_dict() if div else None
     rep.reproducer = write_reproducer(
-        out_dir, rep.kernel, rep.arch, asm, backend, mem, idx, div, lines)
+        out_dir, rep.kernel, rep.arch, asm, mem, idx, div, lines)
 
 
 # ---------------------------------------------------------------------------

@@ -2,9 +2,7 @@
 #
 #   ops       — jit'd instruction-grid runner (decode_fields / init_state /
 #               run_program), the entry point simulate() uses
-#   ref       — pure-jnp cycle step: the reference PE-array semantics
-#   pe_array  — Pallas cycle-step kernel (compiled on the accelerator,
-#               interpreted on the CPU backend)
+#   ref       — pure-jnp cycle step: the PE-array semantics
 #
 # Everything importing this package defers the jax import to first use so
 # mapping-only flows (SAT mapper, DSE sweep, traced-kernel legalization and
@@ -15,7 +13,7 @@
 import os
 from pathlib import Path
 
-_SUBMODULES = ("ops", "pe_array", "ref")
+_SUBMODULES = ("ops", "ref")
 
 # JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
 # unset: one fixed directory in the checkout (the path is part of what a

@@ -1,20 +1,18 @@
 """Jit'd wrappers: run a full instruction grid on the PE-array state.
 
 ``run_program`` scans the decoded (T, P) instruction grid over the cycle
-step — the ref (pure jnp) or the Pallas kernel — carrying the PE-array
-state; batch rides along vectorized.  Any batch size works on both.
+step of ``kernels/ref.py``, carrying the PE-array state; batch rides
+along vectorized, at any size.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..cgra.isa import decode_program
-from .pe_array import cycle_step_pallas, padded_batch
 from .ref import InstrRow, PEState, cycle_step_ref
 
 
@@ -54,31 +52,12 @@ def init_state(batch: int, num_pes: int, mem: np.ndarray) -> PEState:
         mem=jnp.asarray(mem))
 
 
-@functools.partial(jax.jit, static_argnames=("neighbors", "backend", "trace"))
-def run_program(fields: InstrRow, state: PEState, neighbors,
-                backend: str = "ref", trace: bool = True):
-    """Scan all instruction rows. Returns (final state, out trace (T, B, P)).
-
-    The Pallas step takes the batch in whole tiles: any other batch is
-    padded with zero rows, which are independent memories and so inert,
-    and the results are sliced back to the caller's batch."""
-    batch = state.out.shape[0]
-    if backend == "ref":
-        step, padded = cycle_step_ref, batch
-    else:
-        step = cycle_step_pallas
-        padded = padded_batch(batch, state.out.shape[1], state.mem.shape[1])
-    if padded != batch:
-        state = jax.tree_util.tree_map(
-            lambda x: jnp.pad(x, [(0, padded - batch)] + [(0, 0)] * (x.ndim - 1)),
-            state)
+@functools.partial(jax.jit, static_argnames=("neighbors",))
+def run_program(fields: InstrRow, state: PEState, neighbors):
+    """Scan all instruction rows. Returns (final state, out trace (T, B, P))."""
 
     def body(st, row):
-        new = step(st, row, neighbors)
-        return new, (new.out if trace else None)
+        new = cycle_step_ref(st, row, neighbors)
+        return new, new.out
 
-    final, outs = jax.lax.scan(body, state, fields)
-    if padded != batch:
-        final = jax.tree_util.tree_map(lambda x: x[:batch], final)
-        outs = outs[:, :batch] if trace else outs
-    return final, outs
+    return jax.lax.scan(body, state, fields)

@@ -1,25 +1,23 @@
-"""Pure-jnp oracle for the CGRA PE-array cycle step.
+"""The CGRA PE-array cycle step, in pure jnp.
 
-Semantics contract for the Pallas kernel (pe_array.py): given the decoded
-instruction row and the PE-array state, advance one CGRA-cycle.  All integer
-ALU ops are int32 with wrap-around; flags (sign/zero) are per-PE and updated
-by every executed non-NOP op; BSFA/BZFA select between their operands based
-on the *pre-cycle* flags (i.e. the flags of the previous instruction on that
-PE, as in OpenEdgeCGRA).  FXPMUL is ``(a * b) >> FXP_FRAC_BITS`` on the
-int32 product (the high bits of the 64-bit product are lost).  A selector
-that names no source (SRC_ZERO, or a code above it) reads zero.
+This module defines the PE-array semantics: given the decoded instruction
+row and the PE-array state, advance one CGRA-cycle.  All integer ALU ops
+are int32 with wrap-around; flags (sign/zero) are per-PE and updated by
+every executed non-NOP op; BSFA/BZFA select between their operands based
+on the *pre-cycle* flags (i.e. the flags of the previous instruction on
+that PE, as in OpenEdgeCGRA).  FXPMUL is ``(a * b) >> FXP_FRAC_BITS`` on
+the int32 product (the high bits of the 64-bit product are lost).  A
+selector that names no source (SRC_ZERO, or a code above it) reads zero.
 
 The step is dense: operands, ALU result, loads and stores are each chosen
 by elementwise compare-and-select over values the step already holds (a
 selector, an opcode or a word index), so it compiles to no gather and no
-scatter.  :func:`neighbor_outs`, :func:`operand` and :func:`alu` are the
-Pallas step's too.
+scatter.
 
-Contract: two simultaneous stores to the same address in one cycle are
-undefined behaviour (real hardware serializes them through the column port;
-the mapper never schedules them) — the step here keeps the value of the
-highest-numbered PE, the Pallas one-hot store their sum, and the two may
-disagree only in that case.
+Two simultaneous stores to the same address in one cycle are undefined
+behaviour in the ISA (real hardware serializes them through the column
+port; the mapper never schedules them); the step here keeps the value of
+the highest-numbered PE.
 """
 from __future__ import annotations
 

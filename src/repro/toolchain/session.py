@@ -363,7 +363,6 @@ class Toolchain:
         mapping: Mapping,
         mem,
         batch: int = 1,
-        backend: str = "ref",
     ):
         """Run the mapped bitstream on the JAX PE-array simulator
         (requires the ``jax`` extra); returns a
@@ -378,7 +377,7 @@ class Toolchain:
         try:
             from ..cgra.simulator import simulate
 
-            return simulate(prog.builder, mapping, mem, batch=batch, backend=backend)
+            return simulate(prog.builder, mapping, mem, batch=batch)
         except StageError:
             raise
         except Exception as e:

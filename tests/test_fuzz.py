@@ -83,7 +83,7 @@ def test_corpus_touches_only_declared_regions():
 
 def test_corpus_fxp_kernel_clipped_to_declared_range():
     """ema_fxp (the FXPMUL kernel) must never see values outside its
-    declared region range — outside it the jax ref backend's int32
+    declared region range — outside it the JAX cycle step's int32
     product is a known front-end gap, not a mapping bug."""
     regions = kernel_regions("ema_fxp")
     for i in range(20):

@@ -8,8 +8,7 @@ test reads the same statistics as one gather over index pairs worked out
 once per schedule.  Both are compared on the schedules of real mappings
 (mapping needs no JAX) and of random ones, on random int32 traces that
 include INT32_MIN and INT32_MAX, in one block and in several with a
-ragged last one; the last test runs ``fuzz_program`` on the ``ref``
-backend and needs JAX.
+ragged last one; the last test runs ``fuzz_program`` and needs JAX.
 """
 import zlib
 
@@ -278,7 +277,7 @@ def test_shape_mismatch_raises(schedule):
 
 
 # ---------------------------------------------------------------------------
-# fuzz_program on the ref backend (jax-gated)
+# fuzz_program (jax-gated)
 # ---------------------------------------------------------------------------
 
 
@@ -301,7 +300,7 @@ def test_fuzz_program_activity_matches_row_replay(mapped, monkeypatch):
 
     monkeypatch.setattr(simulator, "execute_asm", capturing)
     rep = fuzz_program(prog, mapping, make_corpus("dotprod", 16, seed=5),
-                       batch=8, backend="ref")
+                       batch=8)
     assert rep.ok and len(traces) == 2
     ref = RowReplay(asm, grid)
     for outs in traces:

@@ -107,7 +107,7 @@ def test_span_tree_parentage_and_one_trace(compiled, traced):
     assert parents == ["verify.chunk"] * 2 + ["verify.seam"] * 2
     job = next(r for r in spans.values() if r["name"] == "verify.job")
     assert job["attrs"] == {"kernel": KERNEL, "memories": MEMORIES,
-                            "batch": BATCH, "backend": "ref",
+                            "batch": BATCH,
                             "trip": cr.program.builder.trip,
                             "mem_words": 128}
     for r in spans.values():

@@ -129,7 +129,7 @@ def test_gsm_frame_is_gsm_over_a_whole_frame(frame):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_fuzz_program_matches_plain_reference(frame, case, monkeypatch):
-    """``fuzz_program`` (ref backend) flags no memory, and what its
+    """``fuzz_program`` flags no memory, and what its
     execution seam produced (final memories, last-iteration acc) equals
     the plain reference word for word."""
     pytest.importorskip("jax")

@@ -7,15 +7,19 @@ scatters stores into it.  The step under test (``kernels/ref.py``) picks
 all of them by compare-and-select.  Both run on random rows that cover
 every opcode, every source selector 0-10 and every destination code, over
 random states whose addresses fall in range, below 0 and above the last
-word, for one step, for a 32-row ``run_program`` scan and for one
-``run_stacked`` dispatch.  Two stores to one address in one cycle are
-undefined (the module contract), so the rows never hold them.
+word, for one step, for ``run_program`` scans of 32 and of 6 rows and for
+one ``run_stacked`` dispatch, on the torus and on the mesh (whose edge PEs
+read themselves where the torus wraps).  Two stores to one address in one
+cycle are undefined (the module contract), so the rows never hold them.
 """
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+pytest.importorskip("hypothesis", reason="optional extra: pip install .[test]")
 import jax.numpy as jnp  # noqa: E402
+from hypothesis import HealthCheck, given, settings  # noqa: E402
+from hypothesis import strategies as st  # noqa: E402
 
 from repro.cgra import make_grid  # noqa: E402
 from repro.cgra.bitstream import AssembledCIL  # noqa: E402
@@ -196,12 +200,18 @@ def _assert_same(got, want):
 
 
 SHAPES = [(n, B, M) for n in (4, 6) for M in (128, 512) for B in (1, 7, 64)]
+# small and odd grids, and 64 and 256 words
+SMALL_SHAPES = [(2, 1, 64), (2, 8, 128), (3, 4, 128), (4, 2, 256),
+                (5, 3, 128)]
+TOPOLOGY = pytest.mark.parametrize("torus", [True, False],
+                                   ids=["torus", "mesh"])
 
 
+@TOPOLOGY
 @pytest.mark.parametrize("n,B,M", SHAPES)
-def test_dense_step_matches_indexed_step(n, B, M):
+def test_dense_step_matches_indexed_step(n, B, M, torus):
     rng = np.random.RandomState(n * 1000 + B * 10 + M)
-    nbrs = neighbor_table(make_grid(n, n))
+    nbrs = neighbor_table(make_grid(n, n, torus=torus))
     P = n * n
     state = _state(rng, B, P, M)
     # enough rows from one state for every opcode to run once
@@ -210,16 +220,28 @@ def test_dense_step_matches_indexed_step(n, B, M):
         _assert_same(_dense(state, row, nbrs), _indexed(state, row, nbrs))
 
 
-@pytest.mark.parametrize("n,B,M", SHAPES)
-def test_ref_scan_matches_indexed_steps(n, B, M):
-    rng = np.random.RandomState(n * 1000 + B * 10 + M + 1)
-    nbrs = neighbor_table(make_grid(n, n))
+def _assert_scan_matches(rng, n, B, M, T, torus=True):
+    nbrs = neighbor_table(make_grid(n, n, torus=torus))
     state = _state(rng, B, n * n, M)
-    rows, want_outs, want = _program(rng, state, nbrs, 32, M)
+    rows, want_outs, want = _program(rng, state, nbrs, T, M)
     fields = InstrRow(*(jnp.stack(f) for f in zip(*rows)))
-    final, outs = run_program(fields, state, nbrs, backend="ref")
+    final, outs = run_program(fields, state, nbrs)
     _assert_same(final, want)
     np.testing.assert_array_equal(np.asarray(outs), want_outs)
+
+
+@TOPOLOGY
+@pytest.mark.parametrize("n,B,M", SHAPES + SMALL_SHAPES)
+def test_ref_scan_matches_indexed_steps(n, B, M, torus):
+    rng = np.random.RandomState(n * 1000 + B * 10 + M + 1)
+    _assert_scan_matches(rng, n, B, M, 32, torus)
+
+
+@given(st.integers(0, 10_000))
+@settings(deadline=None, max_examples=10,
+          suppress_health_check=[HealthCheck.too_slow])
+def test_ref_scan_matches_indexed_steps_property(seed):
+    _assert_scan_matches(np.random.RandomState(seed), 2, 2, 64, 6)
 
 
 def test_nop_row_keeps_state_and_flags():
