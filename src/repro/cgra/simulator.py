@@ -77,18 +77,17 @@ class SimResult:
 def preset_state(asm: AssembledCIL, num_pes: int, mem: np.ndarray,
                  batch: int):
     """Initial PE-array state for ``asm``: zeros plus the register/output
-    presets that seed loop-carried values for iteration 0."""
+    presets that seed loop-carried values for iteration 0.  Built on the
+    host, so nothing waits on the device: the dispatch uploads it."""
     # deferred: JAX is an optional extra — mapping (map_for_execution) must
     # work without it; only execution needs the PE-array kernels
     from ..kernels.ops import init_state
     state = init_state(batch, num_pes, mem)
-    out0 = np.array(state.out)
-    regs0 = np.array(state.regs)
     for pe, val in asm.presets_out.items():
-        out0[:, pe] = val
+        state.out[:, pe] = val
     for (pe, reg), val in asm.presets_reg.items():
-        regs0[:, pe, reg] = val
-    return state._replace(out=out0, regs=regs0)
+        state.regs[:, pe, reg] = val
+    return state
 
 
 def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
@@ -99,10 +98,12 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
     fuzzing engine (``repro.fuzz.engine``), which also needs the preset
     initial OUT values for switching-activity harvesting.
 
-    Spans (``repro.obs``): ``verify.seam`` (timed) holds ``verify.decode``,
-    ``verify.preset``, ``verify.dispatch`` (trace, compile on a miss,
-    enqueue), ``verify.wait`` (the device run) and ``verify.transfer``
-    (the OUT trace to the host); ``d2h_bytes`` counts what comes back."""
+    Spans (``repro.obs``): ``verify.seam`` (timed) holds ``verify.decode``
+    and ``verify.preset`` (the instruction fields and the initial state,
+    both built on the host), ``verify.dispatch`` (upload, trace, compile
+    on a miss, enqueue), ``verify.wait`` (the device run) and
+    ``verify.transfer`` (the OUT trace to the host, the one copy back;
+    its ``d2h_bytes`` counts it)."""
     # kept only for the seam fakes of tests/bench/test_bench_faults.py
     if backend != "ref":
         raise ValueError(f"unknown simulator backend {backend!r}")
@@ -114,10 +115,9 @@ def execute_asm(asm: AssembledCIL, grid: PEGrid, mem: np.ndarray,
     with obs_trace.timed_span("verify.seam"):
         with obs_trace.span("verify.decode"):
             fields = decode_fields(asm.words())
-        with obs_trace.span("verify.preset") as sp:
+        with obs_trace.span("verify.preset"):
             state = preset_state(asm, grid.num_pes, mem, batch)
-            sp.set(d2h_bytes=state.out.nbytes + state.regs.nbytes)
-        out0 = np.array(state.out)
+        out0 = state.out
         nbrs = neighbor_table(grid)
         with obs_trace.span("verify.dispatch"):
             final, outs = run_program(fields, state, nbrs)

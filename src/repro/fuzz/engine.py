@@ -319,7 +319,8 @@ def fuzz_program(
     Spans (``repro.obs``): one ``verify.job`` per call (with the loop's
     ``trip`` and the memories' ``mem_words``), a ``verify.chunk`` per
     chunk (with its ``rows``, ``pes`` and ``mem_words``) holding the
-    seam's ``verify.seam`` (see ``execute_asm``), ``verify.nodes``,
+    seam's ``verify.seam`` (see ``execute_asm``: its ``verify.decode`` and
+    ``verify.preset`` build the inputs on the host), ``verify.nodes``,
     ``verify.transfer`` (the final memories to the host, with
     ``d2h_bytes``), ``verify.oracle``, ``verify.compare`` and
     ``verify.activity``.  ``exec_time_s`` and ``oracle_time_s`` are the
@@ -462,25 +463,17 @@ def _energy_delta(program, mapping, activity: Dict) -> Dict:
 def _pad_fields(fields, total_rows: int):
     """NOP-pad decoded instruction fields (T, P) to ``total_rows`` rows.
     NOP rows leave all state untouched, so padding at the end is inert."""
-    import jax.numpy as jnp
-
-    from ..cgra.isa import DST_NONE, SRC_ZERO
+    from ..cgra.isa import DST_NONE, OPCODE, SRC_ZERO
     from ..kernels.ref import InstrRow
 
     T, P = fields.op.shape
     pad = total_rows - T
     if pad == 0:
         return fields
-    z = jnp.zeros((pad, P), jnp.int32)
-    return InstrRow(
-        op=jnp.concatenate([fields.op, z]),
-        dst=jnp.concatenate([fields.dst, jnp.full((pad, P), DST_NONE,
-                                                  jnp.int32)]),
-        sa=jnp.concatenate([fields.sa, jnp.full((pad, P), SRC_ZERO,
-                                                jnp.int32)]),
-        sb=jnp.concatenate([fields.sb, jnp.full((pad, P), SRC_ZERO,
-                                                jnp.int32)]),
-        imm=jnp.concatenate([fields.imm, z]))
+    nop = InstrRow(op=OPCODE["NOP"], dst=DST_NONE, sa=SRC_ZERO, sb=SRC_ZERO,
+                   imm=0)
+    return InstrRow(*(np.concatenate([f, np.full((pad, P), v, np.int32)])
+                      for f, v in zip(fields, nop)))
 
 
 def run_stacked(
@@ -512,12 +505,12 @@ def run_stacked(
     fields = [decode_fields(asm.words()) for asm in asms]
     t_max = max(f.op.shape[0] for f in fields)
     fields = [_pad_fields(f, t_max) for f in fields]
-    stacked_fields = jax.tree_util.tree_map(
-        lambda *xs: jax.numpy.stack(xs), *fields)
+    stacked_fields = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                            *fields)
     states = [preset_state(asm, P, mems[k], B)
               for k, asm in enumerate(asms)]
-    stacked_state = jax.tree_util.tree_map(
-        lambda *xs: jax.numpy.stack(xs), *states)
+    stacked_state = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                           *states)
     nbrs = neighbor_table(grid)
 
     def run_one(f, s):

@@ -9,47 +9,42 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from ..cgra.isa import decode_program
+from ..cgra.isa import OPS
 from .ref import InstrRow, PEState, cycle_step_ref
 
 
 def decode_fields(words: np.ndarray) -> InstrRow:
-    """(T, P) uint32 bitstream -> stacked int32 instruction fields."""
-    rows = decode_program(words)
-    from ..cgra.isa import OPCODE
-    T = len(rows)
-    P = len(rows[0]) if T else 0
-    op = np.zeros((T, P), np.int32)
-    dst = np.zeros((T, P), np.int32)
-    sa = np.zeros((T, P), np.int32)
-    sb = np.zeros((T, P), np.int32)
-    imm = np.zeros((T, P), np.int32)
-    for t, row in enumerate(rows):
-        for p, ins in enumerate(row):
-            op[t, p] = OPCODE[ins.op]
-            dst[t, p] = ins.dst
-            sa[t, p] = ins.src_a
-            sb[t, p] = ins.src_b
-            imm[t, p] = ins.imm
-    return InstrRow(op=jnp.asarray(op), dst=jnp.asarray(dst),
-                    sa=jnp.asarray(sa), sb=jnp.asarray(sb),
-                    imm=jnp.asarray(imm))
+    """(T, P) uint32 bitstream -> stacked int32 instruction fields, as host
+    arrays that ``run_program`` uploads with its call.
+
+    The fields are cut out of the words by the layout of ``cgra/isa.py``
+    (``Instr.decode``): the opcode field is the opcode id, since
+    ``OPCODE[OPS[k]] == k``, and ``imm`` is sign-extended from 16 bits.
+    An opcode field with no entry in ``OPS`` raises."""
+    w = np.asarray(words, np.uint32)
+    op = (w >> 27).astype(np.int32)
+    if op.size and op.max() >= len(OPS):
+        raise ValueError(f"opcode field {op.max()} names no op "
+                         f"(there are {len(OPS)})")
+    return InstrRow(
+        op=op,
+        dst=((w >> 24) & 0x7).astype(np.int32),
+        sa=((w >> 20) & 0xF).astype(np.int32),
+        sb=((w >> 16) & 0xF).astype(np.int32),
+        imm=w.astype(np.uint16).view(np.int16).astype(np.int32))
 
 
 def init_state(batch: int, num_pes: int, mem: np.ndarray) -> PEState:
-    """mem: (batch, M) or (M,) int32 initial memory image."""
+    """Zero PE-array state over ``mem``, a (batch, M) or (M,) int32 memory
+    image, as host arrays that ``run_program`` uploads with its call."""
     mem = np.asarray(mem, np.int32)
     if mem.ndim == 1:
         mem = np.broadcast_to(mem, (batch,) + mem.shape)
-    return PEState(
-        regs=jnp.zeros((batch, num_pes, 4), jnp.int32),
-        out=jnp.zeros((batch, num_pes), jnp.int32),
-        sf=jnp.zeros((batch, num_pes), jnp.int32),
-        zf=jnp.zeros((batch, num_pes), jnp.int32),
-        mem=jnp.asarray(mem))
+    out = np.zeros((batch, num_pes), np.int32)
+    return PEState(regs=np.zeros((batch, num_pes, 4), np.int32), out=out,
+                   sf=np.zeros_like(out), zf=np.zeros_like(out), mem=mem)
 
 
 @functools.partial(jax.jit, static_argnames=("neighbors",))
